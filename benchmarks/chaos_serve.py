@@ -35,12 +35,9 @@ fault rate must stay within 2x of fault-free (acceptance, ISSUE 8).
       [--rounds 6] [--reps 2] [--fault-seed 17] [--smoke] \
       [--baseline BENCH_chaos.json] [--max-regression 0.25] [--json PATH]
 """
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import argparse
 import json
+import os
 import time
 
 from serve_throughput import VOCAB, build_pool, git_commit

@@ -30,13 +30,15 @@ more than `--max-regression` (default 20%) — wired into CI as a soft gate
 (warn, don't fail: the 2-core shared runner swings more than real
 regressions).
 
-`--devices 1 2 8` adds a pod-scale sharded-fleet row set: each device
-count runs in a fresh subprocess under
+`--devices 1 2 8` adds a pod-scale sharded-fleet row set on virtual CPU
+devices: each device count runs in a fresh CPU-only subprocess under
 `--xla_force_host_platform_device_count=N` (the count locks at jax init)
 and times `simulate_fleet(mesh=make_fleet_mesh())` at `--devices-tenants`
-tenants (default 4096). Rows carry a `devices` column plus the worker's
-`host_cores` — virtual CPU devices only parallelize up to the physical
-core count, so scaling numbers are only meaningful when cores ≥ devices.
+tenants (default 4096). The sweep runs before this process imports jax,
+so the parent never holds an accelerator its workers might need. Rows
+carry a `devices` column plus the worker's `host_cores` — virtual CPU
+devices only parallelize up to the physical core count, so scaling
+numbers are only meaningful when cores ≥ devices.
 
   PYTHONPATH=src python benchmarks/fleet_throughput.py \
       [--tenants 1 4 16 64] [--rounds 256] [--kind suc] [--mixed] \
@@ -44,20 +46,13 @@ core count, so scaling numbers are only meaningful when cores ≥ devices.
       [--devices 1 2 8] [--devices-tenants 4096] [--devices-rounds 32] \
       [--baseline BENCH_fleet.json] [--max-regression 0.2] [--json PATH]
 """
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import argparse
 import functools
 import json
+import os
 import subprocess
 import sys
 import time
-
-import jax
-import jax.numpy as jnp
-import numpy as np
 
 KINDS_ALL = ("awc", "suc", "aic")
 
@@ -88,6 +83,8 @@ def slice_pool(pool, k):
 
 def run_single_tenant_loop(pool, cfg, T, key, step_fn):
     """The pre-fleet shape: one jitted round per host call, T host calls."""
+    import jax.numpy as jnp
+
     from repro.router import fleet
     state = fleet.init_tenant_state(1, pool.k, keys=key[None])
     kinds_present = fleet._kinds_present(cfg)
@@ -105,6 +102,9 @@ def bench_engines(pool, kinds, T, reps):
 
 def bench_host_loops(pool, kinds, T):
     """Rounds/sec for the per-call host loop and the unbatched scan."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.router import fleet
     m = len(kinds)
     keys = jax.random.split(jax.random.PRNGKey(0), m)
@@ -167,6 +167,8 @@ def bench_awc_sweep(pool, T, reps, tenants):
 
 def bench_engines_cfg(pool, cfg, m, T, reps):
     """The shared warmup + interleaved best-of-reps engine timing loop."""
+    import jax
+
     from repro.router import fleet
     keys = jax.random.split(jax.random.PRNGKey(0), m)
     best = {"grid": 0.0, "bisect": 0.0}
@@ -184,6 +186,8 @@ def run_device_worker(n, args):
     """Subprocess body for one --devices cell: this process was spawned
     with N forced host devices; time the sharded fleet scan and emit one
     JSON row on stdout for the parent to collect."""
+    import jax
+
     from repro.env.llm_profiles import paper_pool
     from repro.launch.mesh import make_fleet_mesh
     from repro.router import fleet
@@ -215,7 +219,7 @@ def bench_devices(args):
     here = os.path.abspath(__file__)
     for wl in args.workloads or ["awc"]:
         for n in args.devices:
-            env = dict(os.environ)
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             flags = [f for f in env.get("XLA_FLAGS", "").split()
                      if not f.startswith(
                          "--xla_force_host_platform_device_count")]
@@ -324,6 +328,11 @@ def main(argv=None):
         run_device_worker(getattr(args, "_device_worker"), args)
         return
 
+    # the device sweep's workers start before this process touches jax
+    device_rows = bench_devices(args) if args.devices else []
+
+    import jax
+
     from repro.env.llm_profiles import paper_pool
     if args.smoke:
         # keep --rounds at the committed sweep's 256: shorter runs
@@ -368,7 +377,7 @@ def main(argv=None):
 
     if args.devices:
         out["host_cores"] = os.cpu_count()
-        out["results"].extend(bench_devices(args))
+        out["results"].extend(device_rows)
 
     path = args.json or os.path.join(os.path.dirname(
         os.path.abspath(__file__)), "..", "BENCH_fleet.json")

@@ -59,7 +59,8 @@ def main(argv=None):
         q = jax.random.normal(k0, (b, h, s, d))
         k = jax.random.normal(jax.random.fold_in(k0, 1), (b, kv, s, d))
         v = jax.random.normal(jax.random.fold_in(k0, 2), (b, kv, s, d))
-        t1 = bench(lambda: ops.flash_attention(q, k, v, bq=128, bk=128))
+        t1 = bench(lambda: ops.flash_attention(q, k, v, bq=128, bk=128,
+                                                interpret=True))
         t2 = bench(lambda: ref.flash_attention(q, k, v))
         row("flash_attention", f"B{b}H{h}KV{kv}S{s}D{d}", t1, t2)
 
@@ -68,7 +69,8 @@ def main(argv=None):
         kc = jax.random.normal(jax.random.fold_in(k0, 1), (b, t, kv, d))
         vc = jax.random.normal(jax.random.fold_in(k0, 2), (b, t, kv, d))
         pos = jnp.int32(t - 1)
-        t1 = bench(lambda: ops.decode_attention(q, kc, vc, pos, bk=512))
+        t1 = bench(lambda: ops.decode_attention(q, kc, vc, pos, bk=512,
+                                                 interpret=True))
         t2 = bench(lambda: ref.decode_attention(q, kc, vc, pos))
         row("decode_attention", f"B{b}H{h}KV{kv}T{t}D{d}", t1, t2)
 
@@ -103,7 +105,8 @@ def main(argv=None):
         acum = jnp.cumsum(a, axis=2)
         bm = jax.random.normal(jax.random.fold_in(k0, 2), (b, nc, l, n))
         cm = jax.random.normal(jax.random.fold_in(k0, 3), (b, nc, l, n))
-        t1 = bench(lambda: ops.ssd_chunk(xd, acum, bm, cm))
+        t1 = bench(lambda: ops.ssd_chunk(xd, acum, bm, cm,
+                                          interpret=True))
         t2 = bench(lambda: ref.ssd_chunk(xd, acum, bm, cm))
         row("ssd_chunk", f"B{b}NC{nc}L{l}H{h}P{p}N{n}", t1, t2)
 

@@ -31,13 +31,10 @@ Acceptance (ISSUE 6): continuous ≥ 3× sequential tokens/sec at
       [--tenants 1 4 8] [--replicas 3] [--rounds 6] [--reps 2] [--smoke] \
       [--baseline BENCH_serve.json] [--max-regression 0.2] [--json PATH]
 """
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import time
 

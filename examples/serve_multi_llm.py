@@ -9,14 +9,22 @@ trained (cheap, good) model and stops querying the expensive ones.
 Generation is served by the continuous-batching engine: four tenants share
 the pool, so each round their requests coalesce into per-replica slot-cache
 decode batches and bandit feedback is applied asynchronously as each
-completion lands (paper App. E.3).
+completion lands (paper App. E.3). The members are the CPU-sized
+`.reduced()` variants; `python -m repro.launch.serve` builds the published
+widths instead.
 
   PYTHONPATH=src python examples/serve_multi_llm.py
 """
-from repro.launch.serve import main
+import dataclasses
+
+from repro.configs.base import get_config
+from repro.launch.serve import VOCAB, main
+
+POOL = ("h2o-danube-3-4b", "mamba2-780m", "starcoder2-7b")
 
 if __name__ == "__main__":
     main(["--kind", "awc", "--rounds", "25", "--n", "2", "--rho", "0.6",
-          "--pool", "h2o-danube-3-4b,mamba2-780m,starcoder2-7b",
           "--train-first", "1", "--dispatch", "continuous",
-          "--tenants", "4"])
+          "--tenants", "4", "--max-len", "64"],
+         configs=[dataclasses.replace(get_config(nm).reduced(), vocab=VOCAB)
+                  for nm in POOL])

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core import confidence as cb
 from repro.core import rewards as R
+from repro.core.relax import budget_cost
 
 BISECT_ITERS = 48
 DOUBLE_ITERS = 24
@@ -60,11 +61,11 @@ def lp_partition(w, c, groups, caps, rho: float, drop_negative: bool = True):
         return _top_per_group(score, groups, caps_per_arm)
 
     z0 = vertex(jnp.float32(0.0))
-    cost0 = jnp.dot(c, z0)
+    cost0 = budget_cost(c, z0)
 
     def dbl(_, lam):
         zz = vertex(lam)
-        return jnp.where(jnp.dot(c, zz) > rho, lam * 2.0, lam)
+        return jnp.where(budget_cost(c, zz) > rho, lam * 2.0, lam)
 
     lam_hi0 = jax.lax.fori_loop(0, DOUBLE_ITERS, dbl, jnp.float32(1.0))
     z_hi0 = vertex(lam_hi0)
@@ -73,14 +74,14 @@ def lp_partition(w, c, groups, caps, rho: float, drop_negative: bool = True):
         lo, hi, z_l, z_h = carry
         mid = 0.5 * (lo + hi)
         z_m = vertex(mid)
-        feas = jnp.dot(c, z_m) <= rho
+        feas = budget_cost(c, z_m) <= rho
         return (jnp.where(feas, lo, mid), jnp.where(feas, mid, hi),
                 jnp.where(feas, z_l, z_m), jnp.where(feas, z_m, z_h))
 
     _, _, z_lo, z_hi = jax.lax.fori_loop(
         0, BISECT_ITERS, bis, (jnp.float32(0.0), lam_hi0, z0, z_hi0))
-    c_lo = jnp.dot(c, z_lo)
-    c_hi = jnp.dot(c, z_hi)
+    c_lo = budget_cost(c, z_lo)
+    c_hi = budget_cost(c, z_hi)
     theta = jnp.where(c_lo > c_hi,
                       (rho - c_hi) / jnp.maximum(c_lo - c_hi, 1e-12), 0.0)
     theta = jnp.clip(theta, 0.0, 1.0)

@@ -71,7 +71,7 @@ def c2mabv_direct(cfg: PolicyConfig) -> Act:
         mu_bar = cb.reward_ucb(stats, t, cfg.delta, cfg.alpha_mu)
         c_low = cb.cost_lcb(stats, t, cfg.delta, cfg.alpha_c)
         vals = R.set_reward(cfg.kind, actions, mu_bar)
-        cost = actions @ c_low
+        cost = relax.budget_cost(c_low, actions)
         feas = cost <= cfg.rho
         vals = jnp.where(feas, vals, -jnp.inf)
         any_feas = feas.any()

@@ -48,9 +48,9 @@ barely move the Lagrangian breakpoint λ*, so on the grid engine the λ
 bracket found for step t seeds step t+1 — a 2-row revalidation probe
 ({λ_lo, λ_hi}) plus two escape rows plus FW_WARM_ITERS bisection rows
 replaces the full ~25-probe-row cold search (`_grid_tail_warm`; the
-escape schedule guarantees whole-ladder recovery within the fixed trip
-budget, so the warm program is vmap/switch-friendly: no data-dependent
-trip counts).
+escape schedule guarantees whole-ladder recovery, and a lane that
+escaped keeps bisecting until its bracket is as narrow as the cold
+search's — a data-dependent trip count bounded by FW_WARM_MAX_ITERS).
 `fw_steps` (default `FW_STEPS`, env ``REPRO_FW_STEPS``) and `fw_warm`
 (env ``REPRO_FW_WARM``) are trace-time static knobs threaded through every
 solver entry point; warm-started and cold-started FW are decision-
@@ -84,7 +84,7 @@ from repro.kernels import ops as kops
 __all__ = [
     "lp_topn", "lp_topn_dyn", "solve_relaxed", "solve_relaxed_ix",
     "solve_batch", "solve_direct", "enumerate_actions", "stable_desc_ranks",
-    "ENGINES", "DEFAULT_ENGINE",
+    "budget_cost", "ENGINES", "DEFAULT_ENGINE",
 ]
 
 BISECT_ITERS = 48     # bisect engine: sequential bisection depth
@@ -106,13 +106,19 @@ FW_WARM_ITERS = 3      # warm FW: bisection probe rows per step (on top of
 #                        double as bisections when the carried bracket is
 #                        still valid, and refinement compounds across FW
 #                        steps — near-bit-equal to cold FW on the test
-#                        corpus, objective gap ≤ 2e-6)
+#                        corpus, objective gap ≤ 2e-6). A lane whose
+#                        bracket is still wider than the cold search's
+#                        resolution after them keeps bisecting, up to
+#                        FW_WARM_MAX_ITERS rows in all.
 
 LAM_MAX_EXP = 24       # both engines cap λ at 2^LAM_MAX_EXP
 GRID_ROUNDS = 4        # wide lowering: mantissa rounds (incl. the final one)
 GRID_POINTS = 64       # wide lowering: λ probes per round (power of 2)
 GRID_EXP_ITERS = 5     # CPU lowering: integer-exponent bisection depth
 GRID_TAIL_ITERS = 18   # CPU lowering: mantissa bisection depth
+# enough rows to narrow [4·λ, 2^LAM_MAX_EXP] (an `up` escape's bracket) to
+# the cold search's resolution
+FW_WARM_MAX_ITERS = FW_WARM_ITERS + LAM_MAX_EXP + GRID_TAIL_ITERS + 1
 
 ENGINES = ("grid", "bisect")
 DEFAULT_ENGINE = os.environ.get("REPRO_LP_ENGINE", "grid")
@@ -168,10 +174,10 @@ def _lagrangian_costs(w, c, n, lams, equality: bool):
     """cost(λ) = Σ c·z(λ) for a whole λ batch: lams (G,) -> (G,) float32.
 
     Only the scalar reduction is computed; no (G, K) vertex is ever
-    materialized during the search. On TPU the reduction is the tiled
-    Pallas `topn_lp` kernel over (G, K) score rows; elsewhere it is the
+    materialized during the search. On TPU the reduction is the Pallas
+    `topn_lp` kernel over (G, K) score rows; elsewhere it is the
     FMA-proof crossing form (`ranks.lagrangian_topn_cost`)."""
-    if kops.topn_lp_pallas():
+    if kops.use_pallas():
         scores = w[None, :] - lams[:, None] * c[None, :]
         return kops.topn_lp(scores, jnp.broadcast_to(c, scores.shape),
                             jnp.broadcast_to(jnp.asarray(n, jnp.int32),
@@ -248,11 +254,20 @@ def _grid_wide_from_octave(w, c, n, rho, equality: bool, feas):
     # cheapest feasible λ and the costliest infeasible one), which needs no
     # ordering assumption and pairs the true straddling vertices even if a
     # boundary probe flipped during bracketing.
+    # The bracketing probes rank scores w - λ·c (the Pallas kernels); this
+    # batch ranks by crossing thresholds (`lagrangian_topn_mask`). The two
+    # can disagree within a few ulps of a crossing, which may leave every
+    # fine probe on one side of it; a guard one bracketing step outside
+    # each end keeps a vertex of each side in the batch, so the straddle
+    # pairs adjacent vertices instead of falling back to λ = 0.
     step = jnp.float32(2.0 ** (-bits * GRID_ROUNDS))
+    guard = jnp.float32(2.0 ** (-bits * (GRID_ROUNDS - 1)))
     lams = jnp.concatenate([jnp.zeros((1,), jnp.float32),
+                            jnp.maximum(scale * (m_lo - guard), 0.0)[None],
                             jnp.minimum(scale * (m_lo + ks * step), lam_cap),
-                            jnp.minimum(scale * m_hi, lam_cap)[None]])
-    masks = lagrangian_topn_mask(w, c, lams, n, equality)      # (G+2, K)
+                            jnp.minimum(scale * m_hi, lam_cap)[None],
+                            jnp.minimum(scale * (m_hi + guard), lam_cap)[None]])
+    masks = lagrangian_topn_mask(w, c, lams, n, equality)      # (G+4, K)
     costs = (masks * c).sum(-1)
     feas = costs <= rho
     i_hi = jnp.where(feas.any(), jnp.argmin(jnp.where(feas, lams, jnp.inf)),
@@ -436,7 +451,11 @@ def _grid_tail_warm(probe, rho, lam_lo, lam_hi, Zi, Ci):
     bracket still isolates the breakpoint returns the cold answer
     bit-for-bit. FW_WARM_ITERS counts the bisection rows; with the 2-row
     revalidation and 2 escape rows the warm step costs ~8 probe rows
-    against the cold search's ~25."""
+    against the cold search's ~25. A lane that escaped holds a coarse
+    bracket that may span several breakpoints, whose end vertices differ
+    by more than one swap and mix to less than the LP optimum: bisection
+    continues until every lane's bracket is as narrow as the cold
+    search's (relative 2^-(GRID_TAIL_ITERS+1), absolute below λ = 1)."""
     lam_cap = jnp.float32(2.0 ** LAM_MAX_EXP)
     slot = jnp.asarray([False, True])
 
@@ -483,16 +502,26 @@ def _grid_tail_warm(probe, rho, lam_lo, lam_hi, Zi, Ci):
     Z = jnp.where(sel[:, None], z_m, Z)
     C = jnp.where(sel, c_m, C)
 
-    # pure bisection on a now-valid bracket — the cold phase-2 machinery
-    def bis(_, carry):
-        lam, Z, C = carry
+    # pure bisection on a now-valid bracket — the cold phase-2 machinery —
+    # for FW_WARM_ITERS rows, then while the bracket is coarser than the
+    # cold search's
+    tol = jnp.float32(2.0 ** -(GRID_TAIL_ITERS + 1))
+
+    def more(carry):
+        i, lam, _, _ = carry
+        coarse = lam[1] - lam[0] > tol * jnp.maximum(lam[1], 1.0)
+        return (i < FW_WARM_ITERS) | (coarse & ~done
+                                      & (i < FW_WARM_MAX_ITERS))
+
+    def bis(carry):
+        i, lam, Z, C = carry
         mid = 0.5 * (lam[0] + lam[1])
         z_m, c_m = probe(mid)
         sel = (c_m <= rho) == slot
-        return (jnp.where(sel, mid, lam), jnp.where(sel[:, None], z_m, Z),
-                jnp.where(sel, c_m, C))
+        return (i + 1, jnp.where(sel, mid, lam),
+                jnp.where(sel[:, None], z_m, Z), jnp.where(sel, c_m, C))
 
-    lam, Z, C = jax.lax.fori_loop(0, FW_WARM_ITERS, bis, (lam, Z, C))
+    _, lam, Z, C = jax.lax.while_loop(more, bis, (0, lam, Z, C))
     z_mix = _mix_straddle(rho, Z[0], C[0], Z[1], C[1])
     return jnp.where(done, z_done, z_mix), lam[0], lam[1]
 
@@ -507,7 +536,7 @@ def _lp_topn_grid(w, c, n, rho, equality: bool):
     w = w.astype(jnp.float32)
     c = c.astype(jnp.float32)
     rho = jnp.float32(rho)
-    body = _grid_wide if kops.topn_lp_pallas() else _grid_tail
+    body = _grid_wide if kops.use_pallas() else _grid_tail
     return body(w, c, n, rho, equality)
 
 
@@ -522,9 +551,9 @@ def _awc_fw(dyn: bool, mu_bar, c_low, n, rho, engine: Optional[str],
     step seeds the next (`_grid_tail_warm`): ~11 probe rows per warm step
     against the cold search's ~25 — the dominant cost of an AWC tenant
     round on a dispatch-bound host. The wide (accelerator) lowering keeps
-    per-step G-way rounds — batching is free there — and, when the Pallas
-    `awc_fw` kernel is active, fuses the gradient with the octave-ladder
-    probe so no gradient row is materialized between host-level ops.
+    per-step G-way rounds — batching is free there — and fuses the gradient
+    with the octave-ladder probe in the Pallas `awc_fw` kernel, so no
+    gradient row is materialized between host-level ops.
     ``engine="bisect"`` retains the PR-2 cold reference; ``fw_warm=False``
     on the grid engine is the cold-start reference for the warm==cold
     equivalence tests."""
@@ -541,22 +570,16 @@ def _awc_fw(dyn: bool, mu_bar, c_low, n, rho, engine: Optional[str],
 
     c32 = c_low.astype(jnp.float32)
     rho32 = jnp.asarray(rho, jnp.float32)
-    if kops.topn_lp_pallas():
+    if kops.use_pallas():
         # wide lowering: G-way rounds are already one fused batch per
-        # round, so warm-starting buys no rows; the fused kernel (when
-        # active) folds the gradient into the octave probe instead.
-        fused = kops.awc_fw_pallas()
-
+        # round, so warm-starting buys no rows; the fused kernel folds the
+        # gradient into the octave probe instead.
         def fw(i, z):
-            if fused:
-                g, oct_costs = kops.awc_fw(z[None], mu_bar[None], c32[None],
-                                           _octave_ladder()[None],
-                                           jnp.asarray(n, jnp.int32)[None])
-                v = _grid_wide_from_octave(g[0], c32, n, rho32, False,
-                                           oct_costs[0] <= rho32)
-            else:
-                g = R.awc_multilinear_grad(z, mu_bar).astype(jnp.float32)
-                v = _grid_wide(g, c32, n, rho32, False)
+            g, oct_costs = kops.awc_fw(z[None], mu_bar[None], c32[None],
+                                       _octave_ladder()[None],
+                                       jnp.asarray(n, jnp.int32)[None])
+            v = _grid_wide_from_octave(g[0], c32, n, rho32, False,
+                                       oct_costs[0] <= rho32)
             return z + v / fw_steps
         return jax.lax.fori_loop(0, fw_steps, fw, zeros)
 
@@ -594,15 +617,22 @@ def _awc_fw_cont(mu_bar, c32, n, rho32, fw_steps: int, fw_warm: bool,
 
 
 # ============================================================ bisect engine
+def budget_cost(c, z):
+    """Budget-side cost ⟨c, z⟩ (z (K,) or a stack of rows (..., K)) in
+    exact float32: the TPU's default matmul precision would round c to
+    bfloat16 and flip near-budget checks."""
+    return jnp.dot(z, c, precision=jax.lax.Precision.HIGHEST)
+
+
 def _lp_topn_bisect(vertex, w, c, n, rho, equality: bool):
     """Reference engine: sequential λ-doubling then bisection (PR-2 path)."""
     w = w.astype(jnp.float32)
     c = c.astype(jnp.float32)
     z0 = vertex(w, c, n, 0.0, equality)
-    cost0 = jnp.dot(c, z0)
+    cost0 = budget_cost(c, z0)
 
     def cost_at(lam):
-        return jnp.dot(c, vertex(w, c, n, lam, equality))
+        return budget_cost(c, vertex(w, c, n, lam, equality))
 
     # double λ until feasible
     def dbl(_, lam):
@@ -616,7 +646,7 @@ def _lp_topn_bisect(vertex, w, c, n, rho, equality: bool):
         lo, hi, z_l, z_h = carry
         mid = 0.5 * (lo + hi)
         z_m = vertex(w, c, n, mid, equality)
-        feas = jnp.dot(c, z_m) <= rho
+        feas = budget_cost(c, z_m) <= rho
         lo_n = jnp.where(feas, lo, mid)
         hi_n = jnp.where(feas, mid, hi)
         z_l = jnp.where(feas, z_l, z_m)
@@ -625,8 +655,8 @@ def _lp_topn_bisect(vertex, w, c, n, rho, equality: bool):
 
     _, _, z_lo, z_hi = jax.lax.fori_loop(
         0, BISECT_ITERS, bis, (jnp.float32(0.0), lam_hi0, z0, z_hi0))
-    z_mix = _mix_straddle(rho, z_lo, jnp.dot(c, z_lo), z_hi,
-                          jnp.dot(c, z_hi))
+    z_mix = _mix_straddle(rho, z_lo, budget_cost(c, z_lo), z_hi,
+                          budget_cost(c, z_hi))
     return jnp.where(cost0 <= rho, z0, z_mix)
 
 
@@ -724,7 +754,7 @@ def solve_relaxed_ix(kind_ix, mu_bar, c_low, n, rho,
     present = tuple(sorted(set(kinds_present)))
     if len(present) == 1:
         return branches[present[0]]()
-    if _resolve_engine(engine) == "grid" and not kops.topn_lp_pallas():
+    if _resolve_engine(engine) == "grid" and not kops.use_pallas():
         return _solve_ix_unified(kind_ix, mu_bar, c_low, n, rho, present,
                                  fw_steps, fw_warm)
     lut = np.zeros(len(branches), np.int32)      # kind index -> branch slot

@@ -9,9 +9,11 @@ jit'd entry points with backend dispatch in `ops.py`):
   topn_lp           — top-n-by-score cost reduction over (B, K) rows with
                       traced per-row n: the parametric-LP grid engine's
                       scalar cost probe (bandit side; `core.relax`)
+  awc_fw            — the AWC Frank-Wolfe step: multilinear gradient fused
+                      with the octave-ladder probes (bandit side)
 
-On CPU the kernels run in interpret mode (tests/benchmarks only — the
-`topn_lp` op dispatches to the fused pure-jnp path there instead, see
-`ops.topn_lp_pallas`); on TPU set ``REPRO_PALLAS_INTERPRET=0`` for compiled
-kernels.
+Every kernel compiles for the TPU unless its caller passes
+``interpret=True`` (tests and `benchmarks/kernel_bench.py`, on the CPU).
+The router kernels run only on TPU; elsewhere `ops` dispatches to the fused
+pure-jnp path (`ops.use_pallas`).
 """

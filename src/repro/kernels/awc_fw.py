@@ -12,11 +12,12 @@ top-n cost reductions of the Lagrangian scores g − λ·c:
 
 Host-level lowerings materialize the (B, K) gradient between the gradient
 op and every probe op; this kernel keeps (z̃, μ, c) resident in VMEM,
-computes g once per row block, and loops the λ probes over it — the same
-tile-by-tile stable-rank accumulation as `kernels/topn_lp.py` (lower index
-wins ties; selection semantics identical to `core.ranks`). The kernel is
-AWC-specific: ``equality=False`` (the inclusive matroid of the FW oracle)
-is baked in.
+computes g once per row block, and loops the λ probes over it with the
+stable-rank accumulation of `kernels/topn_lp.py` (lower index wins ties;
+selection semantics identical to `core.ranks`). Probe λ's are read and
+their costs written through masked lane ops, never dynamic lane slices.
+The kernel is AWC-specific: ``equality=False`` (the inclusive matroid of
+the FW oracle) is baked in.
 
 Outputs: (g (B, K) float32, costs (B, G) float32).
 """
@@ -28,22 +29,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-NEG = -1e30          # score pad: below any real Lagrangian score
-DEFAULT_BB = 8       # rows per grid cell
-DEFAULT_KT = 128     # arm-axis tile (lane width)
+from repro.kernels.topn_lp import DEFAULT_BB, LANES, NEG, stable_ranks
 
 
-def _kernel(z_ref, mu_ref, c_ref, lam_ref, n_ref, g_ref, out_ref, *,
-            kt: int, k_real: int):
+def _kernel(z_ref, mu_ref, c_ref, lam_ref, n_ref, g_ref, out_ref, *, k: int):
     z = z_ref[...]                                       # (bb, kp)
     mu = mu_ref[...]
     c = c_ref[...]
     lams = lam_ref[...]                                  # (bb, gp)
     n = n_ref[...]                                       # (bb, 1) int32
-    bb, kp = z.shape
-    gp = lams.shape[1]
-    col = jax.lax.broadcasted_iota(jnp.int32, (bb, kp), 1)
-    valid = col < k_real
+    valid = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) < k
+    gcol = jax.lax.broadcasted_iota(jnp.int32, lams.shape, 1)
 
     # multilinear gradient, log-space (mirrors rewards.awc_multilinear_grad;
     # padded arms have μ = 0 -> log1p(0) = 0, so they drop out of the sum)
@@ -54,37 +50,27 @@ def _kernel(z_ref, mu_ref, c_ref, lam_ref, n_ref, g_ref, out_ref, *,
     g_ref[...] = g
 
     def one_lam(gi, costs):
-        lam = jax.lax.dynamic_slice(lams, (0, gi), (bb, 1))  # (bb, 1)
+        sel = gcol == gi
+        lam = jnp.sum(jnp.where(sel, lams, 0.0), axis=1, keepdims=True)
         pos = g > lam * c                    # inclusive matroid: s_k > 0
         s = jnp.where(valid, g - lam * c, NEG)
-
-        def tile(jt, ranks):
-            sj = jax.lax.dynamic_slice(s, (0, jt * kt), (bb, kt))
-            cj = jt * kt + jax.lax.broadcasted_iota(jnp.int32, (bb, kt), 1)
-            beats = (sj[:, None, :] > s[:, :, None]) | (
-                (sj[:, None, :] == s[:, :, None])
-                & (cj[:, None, :] < col[:, :, None]))    # (bb, kp, kt)
-            return ranks + beats.sum(-1).astype(jnp.int32)
-
-        ranks = jax.lax.fori_loop(0, kp // kt, tile,
-                                  jnp.zeros((bb, kp), jnp.int32))
         # arithmetic mask, mirroring core.ranks.topn_lp_cost
-        mask = (ranks < n).astype(jnp.float32) * pos
-        cost = jnp.sum(mask * c, axis=-1, keepdims=True)
-        return jax.lax.dynamic_update_slice(costs, cost, (0, gi))
+        mask = (stable_ranks(s, k) < n).astype(jnp.float32) * pos
+        cost = jnp.sum(mask * c, axis=-1, keepdims=True)    # (bb, 1)
+        return jnp.where(sel, cost, costs)
 
-    out_ref[...] = jax.lax.fori_loop(0, gp, one_lam,
-                                     jnp.zeros((bb, gp), jnp.float32))
+    out_ref[...] = jax.lax.fori_loop(0, lams.shape[1], one_lam,
+                                     jnp.zeros(lams.shape, jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("bb", "kt", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def awc_fw(z, mu, cost, lams, n, *, bb: int = DEFAULT_BB,
-           kt: int = DEFAULT_KT, interpret: bool = True):
+           interpret: bool = False):
     """z/mu/cost (B, K); lams (B, G); n (B,) int32 -> (g (B, K), (B, G))."""
     b, k = z.shape
     g_pts = lams.shape[1]
     bp = -(-b // bb) * bb
-    kp = -(-k // kt) * kt
+    kp = -(-k // LANES) * LANES
 
     def pad(x, fill=0.0):
         out = jnp.full((bp, kp), fill, jnp.float32)
@@ -96,7 +82,7 @@ def awc_fw(z, mu, cost, lams, n, *, bb: int = DEFAULT_BB,
         jnp.asarray(n, jnp.int32))
 
     g, costs = pl.pallas_call(
-        functools.partial(_kernel, kt=kt, k_real=k),
+        functools.partial(_kernel, k=k),
         grid=(bp // bb,),
         in_specs=[
             pl.BlockSpec((bb, kp), lambda i: (i, 0)),

@@ -65,7 +65,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def decode_attention(q, k, v, pos, *, bk: int = DEFAULT_BK,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """q (B,1,H,hd); cache k/v (B,T,KV,hd); pos scalar or (B,) int32 (last
     valid slot per row)."""
     b, _, h, hd = q.shape

@@ -89,7 +89,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q (B,S,H,hd), k/v (B,T,KV,hd) -> (B,S,H,hd)."""
     b, s, h, hd = q.shape
     t, kv = k.shape[1], k.shape[2]

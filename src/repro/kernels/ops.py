@@ -1,11 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) for compiled kernels.
+The router kernels (`topn_lp`, `awc_fw`) are chosen by platform: the
+compiled Pallas kernel on TPU, the fused pure-jnp oracle elsewhere. Every
+wrapper compiles its kernel unless the caller passes ``interpret=True``,
+which only tests and `benchmarks/kernel_bench.py` do (off-TPU).
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -18,65 +19,48 @@ from repro.kernels import topn_lp as _topn
 from repro.kernels import ref as _ref
 
 
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+def use_pallas() -> bool:
+    """Whether the router's probe reductions run through the Pallas kernels
+    — and so whether the relax grid engine takes its wide G-way lowering
+    with the fused `awc_fw` Frank-Wolfe step. The probes sit inside the
+    fleet's jitted scan, where interpret mode is never acceptable: compiled
+    kernels on TPU, the fused pure-jnp path elsewhere."""
+    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, bq: int = _fa.DEFAULT_BQ,
-                    bk: int = _fa.DEFAULT_BK):
+                    bk: int = _fa.DEFAULT_BK, interpret: bool = False):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               bq=bq, bk=bk, interpret=_interpret())
+                               bq=bq, bk=bk, interpret=interpret)
 
 
-def decode_attention(q, k, v, pos, *, bk: int = _dec.DEFAULT_BK):
-    return _dec.decode_attention(q, k, v, pos, bk=bk, interpret=_interpret())
+def decode_attention(q, k, v, pos, *, bk: int = _dec.DEFAULT_BK,
+                     interpret: bool = False):
+    return _dec.decode_attention(q, k, v, pos, bk=bk, interpret=interpret)
 
 
-def ssd_chunk(xd, acum, bm, cm):
-    return _ssd.ssd_chunk(xd, acum, bm, cm, interpret=_interpret())
+def ssd_chunk(xd, acum, bm, cm, *, interpret: bool = False):
+    return _ssd.ssd_chunk(xd, acum, bm, cm, interpret=interpret)
 
 
-def topn_lp_pallas() -> bool:
-    """Whether `topn_lp` routes to the Pallas kernel (and whether the relax
-    grid engine probes through it). The probes sit inside the fleet's
-    jitted scan, so unlike the model-side kernels interpret mode is never
-    acceptable there: default to the compiled kernel on TPU and the fused
-    pure-jnp path elsewhere. ``REPRO_TOPN_LP_PALLAS=1`` forces the kernel
-    (interpret off-TPU — for tests/benchmarks only)."""
-    env = os.environ.get("REPRO_TOPN_LP_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() == "tpu"
+def topn_lp(score, cost, n, *, equality: bool = True,
+            interpret: bool = False):
+    """Top-n-by-score cost reduction: score/cost (B, K), n int/(B,) -> (B,).
 
-
-def topn_lp(score, cost, n, *, equality: bool = True):
-    """Top-n-by-score cost reduction: score/cost (B, K), n int/(B,) -> (B,)."""
-    if topn_lp_pallas():
+    The Pallas kernel on TPU, or in interpret mode where the caller asks
+    for it; the pure-jnp oracle otherwise."""
+    if interpret or use_pallas():
         return _topn.topn_lp(score, cost, n, equality=equality,
-                             interpret=_interpret())
+                             interpret=interpret)
     return _ref.topn_lp(score, cost, n, equality=equality)
 
 
-def awc_fw_pallas() -> bool:
-    """Whether `awc_fw` routes to the fused Pallas kernel (and whether the
-    AWC Frank-Wolfe wide lowering folds its gradient into the octave
-    probe). Same contract as `topn_lp_pallas`: compiled kernel on TPU,
-    fused pure-jnp path elsewhere; ``REPRO_AWC_FW_PALLAS=1`` forces the
-    kernel (interpret off-TPU — for tests/benchmarks only)."""
-    env = os.environ.get("REPRO_AWC_FW_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() == "tpu"
-
-
-def awc_fw(z, mu, cost, lams, n):
+def awc_fw(z, mu, cost, lams, n, *, interpret: bool = False):
     """Fused AWC FW step oracle: gradient + λ-probe cost reductions.
 
-    z/mu/cost (B, K), lams (B, G), n (B,) -> (g (B, K), costs (B, G))."""
-    if awc_fw_pallas():
-        return _awc.awc_fw(z, mu, cost, lams, n, interpret=_interpret())
+    z/mu/cost (B, K), lams (B, G), n (B,) -> (g (B, K), costs (B, G)).
+    Dispatch as in `topn_lp`."""
+    if interpret or use_pallas():
+        return _awc.awc_fw(z, mu, cost, lams, n, interpret=interpret)
     return _ref.awc_fw(z, mu, cost, lams, n)

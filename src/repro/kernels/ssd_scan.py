@@ -46,7 +46,7 @@ def _kernel(xd_ref, acum_ref, b_ref, c_ref, y_ref, st_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk(xd, acum, bm, cm, *, interpret: bool = True):
+def ssd_chunk(xd, acum, bm, cm, *, interpret: bool = False):
     """Intra-chunk SSD.
 
     xd (B,NC,L,H,P), acum (B,NC,L,H), bm/cm (B,NC,L,N)

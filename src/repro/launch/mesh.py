@@ -1,57 +1,62 @@
-"""Production meshes (TPU v5e numbers) + the fleet tenant mesh.
+"""Mesh builders + the fleet tenant mesh.
 
 Mesh builders are functions, not module constants, so importing this module
-never touches jax device state; CLI entry points set XLA_FLAGS before any
-jax import (see `repro.launch.hostdev`).
+never touches jax device state. Every mesh is built by `make_mesh` with
+Auto axes: `jax.make_mesh` defaults to Explicit axes, under which the
+`with_sharding_constraint` of `sharding.shard` acts as an assert.
 
 Run as a module this is the real-mesh fleet smoke: it builds an N-device
-`(pod, data)` mesh (forcing N virtual host devices when --devices is
-given), advances a small fleet through the sharded scan, and verifies the
-trajectory bit-for-bit against the single-device reference:
+`(pod, data)` mesh, advances a small fleet through the sharded scan, and
+verifies the trajectory bit-for-bit against the single-device reference.
+Without ``--devices`` the mesh spans the attached devices (the chips of a
+TPU host); ``--devices N`` instead forces N virtual CPU devices and runs
+mesh and reference on the CPU only:
 
   PYTHONPATH=src python -m repro.launch.mesh --devices 8 --tenants 64 \
       --rounds 32 [--pods 2] [--workload mixed] [--ckpt-dir DIR]
 """
 from __future__ import annotations
 
-import os
 import sys
 
 if __name__ == "__main__" and "--devices" in sys.argv:
     # must precede the jax import below: the device count locks at init
     from repro.launch.hostdev import force_host_device_count
     force_host_device_count(int(sys.argv[sys.argv.index("--devices") + 1]))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
+from jax.sharding import AxisType
 
-# --- hardware constants (TPU v5e) -----------------------------------------
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
-CHIP_HBM_BYTES = 16 * 2**30  # 16 GiB per chip
+
+def make_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with every axis Auto (see the module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_cpu_mesh():
     """Degenerate 1-device mesh for CPU smoke paths."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
-def make_fleet_mesh(n_devices: int = 0, *, pods: int = 1):
-    """Tenant mesh for the sharded fleet scan (router.fleet): all devices
-    on the `(pod, data)` axes the "tenants" logical axis shards over."""
-    n = n_devices or len(jax.devices())
+def make_fleet_mesh(devices=None, *, pods: int = 1):
+    """Tenant mesh for the sharded fleet scan (router.fleet): all
+    ``devices`` (default: every attached device) on the `(pod, data)` axes
+    the "tenants" logical axis shards over."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
     if pods > 1:
         if n % pods:
             raise ValueError(f"{n} devices don't split into {pods} pods")
-        return jax.make_mesh((pods, n // pods), ("pod", "data"))
-    return jax.make_mesh((n,), ("data",))
+        return make_mesh((pods, n // pods), ("pod", "data"), devices)
+    return make_mesh((n,), ("data",), devices)
 
 
 def n_chips(mesh) -> int:
@@ -59,11 +64,22 @@ def n_chips(mesh) -> int:
 
 
 # ============================================================ fleet smoke
-def fleet_smoke(n_devices: int, tenants: int, rounds: int, *, pods: int = 1,
+def _peak_bytes(devices):
+    """Per-device peak memory where the backend reports it (None on CPU)."""
+    stats = [d.memory_stats() for d in devices]
+    if any(st is None for st in stats):
+        return None
+    return [int(st.get("peak_bytes_in_use", 0)) for st in stats]
+
+
+def fleet_smoke(devices, tenants: int, rounds: int, *, pods: int = 1,
                 workload: str = "mixed", ckpt_dir=None, ckpt_every: int = 0,
                 seed: int = 0) -> dict:
-    """Sharded fleet run on a real mesh, verified against the single-device
-    reference. Returns a summary record (printed as JSON by the CLI)."""
+    """Sharded fleet run on a mesh of ``devices``, verified bit-for-bit
+    against the single-device reference on ``devices[0]``. Returns a
+    summary record (printed as JSON by the CLI). ``peak_bytes`` is each
+    device's peak memory right after the sharded run — every device of the
+    mesh must have held its share of the tenants, not just the first."""
     import time
 
     import numpy as np
@@ -72,24 +88,27 @@ def fleet_smoke(n_devices: int, tenants: int, rounds: int, *, pods: int = 1,
     from repro.env.llm_profiles import default_rho, paper_pool
     from repro.router import fleet
 
+    devices = list(devices)
     pool = paper_pool("sciq")
     kinds = [("awc", "suc", "aic")[i % 3] for i in range(tenants)] \
         if workload == "mixed" else [workload] * tenants
     pcfgs = [PolicyConfig(kind=k, k=pool.k, n=4,
                           rho=default_rho(pool, k, 4), delta=1.0 / rounds)
              for k in kinds]
-    cfg = fleet.fleet_config(pcfgs)
-    keys = jax.random.split(jax.random.PRNGKey(seed), tenants)
-    mesh = make_fleet_mesh(n_devices, pods=pods)
+    mesh = make_fleet_mesh(devices, pods=pods)
     axes = fleet.fleet_mesh_axes(tenants, mesh)
-
-    t0 = time.perf_counter()
-    sharded = fleet.simulate_fleet(pool, cfg, T=rounds, keys=keys, mesh=mesh,
-                                   ckpt_dir=ckpt_dir, ckpt_every=ckpt_every)
-    dt_sharded = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    ref = fleet.simulate_fleet(pool, cfg, T=rounds, keys=keys)
-    dt_single = time.perf_counter() - t0
+    with jax.default_device(devices[0]):
+        cfg = fleet.fleet_config(pcfgs)
+        keys = jax.random.split(jax.random.PRNGKey(seed), tenants)
+        t0 = time.perf_counter()
+        sharded = fleet.simulate_fleet(pool, cfg, T=rounds, keys=keys,
+                                       mesh=mesh, ckpt_dir=ckpt_dir,
+                                       ckpt_every=ckpt_every)
+        dt_sharded = time.perf_counter() - t0
+        peaks = _peak_bytes(devices)
+        t0 = time.perf_counter()
+        ref = fleet.simulate_fleet(pool, cfg, T=rounds, keys=keys)
+        dt_single = time.perf_counter() - t0
 
     bit_equal = (
         np.array_equal(sharded.action, ref.action[:, sharded.t0:])
@@ -98,21 +117,26 @@ def fleet_smoke(n_devices: int, tenants: int, rounds: int, *, pods: int = 1,
         and all(np.array_equal(sharded.state.stats[n], ref.state.stats[n])
                 for n in ref.state.stats)
         and np.array_equal(sharded.state.key, ref.state.key))
-    return {"devices": n_chips(mesh), "pods": pods, "tenants": tenants,
-            "rounds": rounds, "workload": workload,
+    return {"devices": n_chips(mesh), "platform": devices[0].platform,
+            "pods": pods, "tenants": tenants, "rounds": rounds,
+            "workload": workload,
             "tenant_axes": list(axes) if axes else None,
             "sharded": axes is not None, "bit_equal": bool(bit_equal),
-            "rps_sharded": round(tenants * rounds / dt_sharded, 1),
-            "rps_single": round(tenants * rounds / dt_single, 1)}
+            "peak_bytes": peaks,
+            # first calls: compilation included
+            "seconds_sharded": dt_sharded, "seconds_single": dt_single}
 
 
 def _main(argv=None):
     import argparse
     import json
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser(description="real-mesh fleet smoke")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N virtual host devices (0 = use existing)")
+                    help="run on N virtual CPU devices (0 = the attached "
+                         "devices)")
     ap.add_argument("--pods", type=int, default=1)
     ap.add_argument("--tenants", type=int, default=64)
     ap.add_argument("--rounds", type=int, default=32)
@@ -122,7 +146,9 @@ def _main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    rec = fleet_smoke(args.devices, args.tenants, args.rounds,
+    enable_compile_cache()
+    devices = jax.devices("cpu") if args.devices else jax.devices()
+    rec = fleet_smoke(devices, args.tenants, args.rounds,
                       pods=args.pods, workload=args.workload,
                       ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                       seed=args.seed)

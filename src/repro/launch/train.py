@@ -20,6 +20,7 @@ from repro.ckpt import checkpoint
 from repro.configs.base import get_config
 from repro.data.pipeline import DataConfig, SyntheticLM, make_batch
 from repro.launch import mesh as mesh_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.sharding import tree_shardings, use_mesh
 from repro.train import optimizer as opt
@@ -41,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -9,6 +9,7 @@ A model is described by a *schema*: a pytree whose leaves are ``ParamSpec``s
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
@@ -34,6 +35,13 @@ def is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "std", "dtype"))
+def _normal(key, *, shape, std, dtype):
+    # jitted so the float32 draw fuses into the cast: eagerly, a bf16 leaf
+    # of N elements would hold 8N bytes of float32 temporaries on device
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
 def init_from_schema(schema, key, dtype_override: Optional[str] = None):
     leaves, treedef = jax.tree.flatten(schema, is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
@@ -45,8 +53,7 @@ def init_from_schema(schema, key, dtype_override: Optional[str] = None):
         elif spec.init == "ones":
             arr = jnp.ones(spec.shape, dt)
         else:
-            arr = (jax.random.normal(k, spec.shape, jnp.float32)
-                   * spec.std).astype(dt)
+            arr = _normal(k, shape=spec.shape, std=spec.std, dtype=dt)
         out.append(arr)
     return jax.tree.unflatten(treedef, out)
 

@@ -234,15 +234,9 @@ def apply_moe_ep(cfg, p, x, *, mesh, batch_axes, expert_axis="model",
                 fsdp if len(fsdp) > 1 else (fsdp[0] if fsdp else None), None)
     in_specs = (P(None, expert_axis), wi_spec, wi_spec, wo_spec, bspec)
     out_specs = (bspec, P())
-    if hasattr(jax, "shard_map"):           # jax >= 0.5 top-level spelling
-        smap = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    else:                                   # 0.4.x: experimental, check_rep
-        from jax.experimental.shard_map import shard_map
-        smap = shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    out = smap(p["router"], p["wi_gate"], p["wi_up"], p["wo"], x)
-    return out
+    smap = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+    return smap(p["router"], p["wi_gate"], p["wi_up"], p["wo"], x)
 
 
 def apply_moe_auto(cfg, p, x):

@@ -265,14 +265,9 @@ def _scan_fleet_sharded(state0: TenantState, cfg: FleetConfig, mu, mean_cost,
 
     in_specs = (state_spec, cfg_spec, P(), P(), P())
     out_specs = (state_spec, (rowp, rowp, matp, matp))
-    if hasattr(jax, "shard_map"):           # jax >= 0.5 top-level spelling
-        smap = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    else:                                   # 0.4.x: experimental, check_rep
-        from jax.experimental.shard_map import shard_map
-        smap = shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    return smap(state0, cfg, mu, mean_cost, t0)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(
+        state0, cfg, mu, mean_cost, t0)
 
 
 def _kinds_present(cfg: FleetConfig) -> Tuple[int, ...]:

@@ -135,13 +135,17 @@ class MultiLLMService:
 
     # --------------------------------------------------------------- quality
     def _quality(self, prompts: np.ndarray, gen: np.ndarray) -> float:
-        """Fraction of generated bigrams that follow the planted graph."""
+        """Fraction of generated bigrams that follow the planted graph. A
+        member's vocabulary may be wider than the query stream's: a token
+        outside the stream's vocabulary is never a valid successor, nor the
+        predecessor of one."""
         succ = self.data.succ
         seq = np.concatenate([prompts[:, -1:], gen], axis=1)
         prev = seq[:, :-1]
         nxt = seq[:, 1:]
-        valid = (succ[prev] == nxt[..., None]).any(-1)
-        return float(valid.mean())
+        in_vocab = prev < succ.shape[0]
+        follows = succ[np.where(in_vocab, prev, 0)] == nxt[..., None]
+        return float((in_vocab & follows.any(-1)).mean())
 
     # ---------------------------------------------------------------- rounds
     def _availability(self) -> Optional[np.ndarray]:
@@ -347,11 +351,14 @@ class FleetService:
                             scheduler=self.sched, tenant=i, seed=seed + i,
                             **service_kw)
             for i, p in enumerate(pcfgs)]
+        self.last_completions = []
 
     def step(self) -> List[RoundLog]:
+        """One round for every tenant. The round's completions, in arrival
+        order, stay readable as ``last_completions``."""
         for svc in self.tenants:
             svc.begin_round()
-        self.sched.drain()
+        self.last_completions = self.sched.drain()
         return [svc.finish_round() for svc in self.tenants]
 
     def run(self, rounds: int) -> List[List[RoundLog]]:

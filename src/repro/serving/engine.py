@@ -100,6 +100,9 @@ class Engine:
         self.dtype = dtype
         # audio: encoder length is an engine property (tests use short stubs)
         self.enc_frames = enc_frames or M.WHISPER_ENC_FRAMES
+        # params are an argument of every jitted program, never a closure
+        # constant: at published widths a captured copy would be compiled
+        # into each executable (GBs of host memory per program)
         self._gen = jax.jit(self._generate, static_argnames=("max_new",))
         self._prefill_jit = jax.jit(self._prefill)
         # the slot state is threaded linearly through admit/decode/release,
@@ -109,7 +112,7 @@ class Engine:
         self._admit_jit = jax.jit(self._admit, donate_argnums=0)
         self._decode_jit = jax.jit(self._decode_chunk,
                                    static_argnames=("steps",),
-                                   donate_argnums=0)
+                                   donate_argnums=1)
         self._release_jit = jax.jit(self._release, donate_argnums=0)
 
     # ------------------------------------------------------------- internals
@@ -126,14 +129,14 @@ class Engine:
                                          self.dtype)
         return inputs
 
-    def _prefill(self, prompts):
-        return M.prefill(self.cfg, self.params, self._inputs(prompts),
+    def _prefill(self, params, prompts):
+        return M.prefill(self.cfg, params, self._inputs(prompts),
                          self.max_len, cache_dtype=self.dtype)
 
-    def _generate(self, prompts, base_key, *, max_new: int):
+    def _generate(self, params, prompts, base_key, *, max_new: int):
         cfg = self.cfg
         b, s = prompts.shape
-        last, cache = self._prefill(prompts)
+        last, cache = self._prefill(params, prompts)
         pos0 = M.prefill_len(cfg, s)
         rkeys = _row_keys(base_key, b)
 
@@ -145,7 +148,7 @@ class Engine:
             lp_sum = lp_sum + jnp.where(finished, 0.0, chosen)
             n_out = n_out + (~finished).astype(jnp.int32)
             finished = finished | (tok == self.eos_id)
-            lg, cache = M.decode_step(cfg, self.params, tok[:, None],
+            lg, cache = M.decode_step(cfg, params, tok[:, None],
                                       cache, pos0 + j)
             return (cache, lg[:, 0], finished, lp_sum, n_out), tok
 
@@ -164,7 +167,7 @@ class Engine:
         engine's ``enc_frames``."""
         max_out = max_out or self.max_len
         dummy = jnp.zeros((1, 1), jnp.int32)
-        _, abs_cache = jax.eval_shape(self._prefill, dummy)
+        _, abs_cache = jax.eval_shape(self._prefill, self.params, dummy)
         cache = jax.tree.map(
             lambda a: jnp.zeros((a.shape[0], n_slots) + a.shape[2:], a.dtype),
             abs_cache)
@@ -186,7 +189,7 @@ class Engine:
     def prefill(self, prompts) -> Tuple[jnp.ndarray, Any]:
         """Prompt phase: (next-token logits (B, V), cache_slice) — the slice
         `admit` writes into free slots."""
-        return self._prefill_jit(jnp.asarray(prompts, jnp.int32))
+        return self._prefill_jit(self.params, jnp.asarray(prompts, jnp.int32))
 
     def _admit(self, state: SlotState, slot_ix, lg, cache_slice,
                rkeys, pos0, max_new):
@@ -239,7 +242,7 @@ class Engine:
         return self._admit_jit(state, slot_ix, lg, cache_slice, rkeys,
                                jnp.int32(pos0), jnp.asarray(mn))
 
-    def _decode_chunk(self, state: SlotState, *, steps: int):
+    def _decode_chunk(self, params, state: SlotState, *, steps: int):
         n_slots = state.pos.shape[0]
         rows = jnp.arange(n_slots)
         max_out = state.out.shape[1]
@@ -264,7 +267,7 @@ class Engine:
             # non-live rows feed EOS at a frozen pos — their cache rows may
             # rot, but results are already in `out` and admit overwrites the
             # full slice on reuse, so no gating of the cache is needed
-            lg, cache = M.decode_step(self.cfg, self.params, tok[:, None],
+            lg, cache = M.decode_step(self.cfg, params, tok[:, None],
                                       state.cache, state.pos)
             return state._replace(
                 cache=cache, last=lg[:, 0].astype(state.last.dtype),
@@ -279,7 +282,7 @@ class Engine:
     def decode_chunk(self, state: SlotState, steps: int) -> SlotState:
         """Advance every occupied slot ``steps`` tokens in one jitted scan.
         `state` is donated (updated in place) — use the returned state."""
-        return self._decode_jit(state, steps=steps)
+        return self._decode_jit(self.params, state, steps=steps)
 
     def _release(self, state: SlotState, slot_ix):
         return state._replace(active=state.active.at[slot_ix].set(False))
@@ -293,6 +296,6 @@ class Engine:
                  seed: int = 0) -> GenResult:
         """Blocking per-request reference path (prefill + jitted decode)."""
         prompts = jnp.asarray(prompts, jnp.int32)
-        toks, n_out, lp = self._gen(prompts, jax.random.PRNGKey(seed),
-                                    max_new=max_new)
+        toks, n_out, lp = self._gen(self.params, prompts,
+                                    jax.random.PRNGKey(seed), max_new=max_new)
         return GenResult(np.asarray(toks), np.asarray(n_out), np.asarray(lp))

@@ -52,15 +52,15 @@ def _mesh() -> Optional[Mesh]:
 
 @contextlib.contextmanager
 def use_mesh(mesh: Optional[Mesh]):
-    """Activate a mesh for logical sharding (None = no-op, CPU smoke path)."""
+    """Activate a mesh for logical sharding (None = no-op, CPU smoke path).
+
+    Build the mesh with `repro.launch.mesh.make_mesh` (Auto axes): on a
+    mesh of Explicit axes `shard` would assert instead of constrain."""
     prev = getattr(_CTX, "mesh", None)
     _CTX.mesh = mesh
     try:
         if mesh is not None:
-            # jax >= 0.5 spells the ambient-mesh context jax.sharding.set_mesh;
-            # on 0.4.x the Mesh object itself is the context manager.
-            setter = getattr(jax.sharding, "set_mesh", None)
-            with (setter(mesh) if setter is not None else mesh):
+            with jax.sharding.set_mesh(mesh):
                 yield mesh
         else:
             yield None

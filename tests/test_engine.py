@@ -219,12 +219,12 @@ def test_decode_attention_per_row_pos():
     k = jax.random.normal(jax.random.fold_in(k0, 1), (b, t, kv, hd))
     v = jax.random.normal(jax.random.fold_in(k0, 2), (b, t, kv, hd))
     pos = jnp.asarray([0, 5, 63, 127], jnp.int32)
-    out = ops.decode_attention(q, k, v, pos)
+    out = ops.decode_attention(q, k, v, pos, interpret=True)
     want = ref.decode_attention(q, k, v, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
     for i in range(b):
         row = ops.decode_attention(q[i:i + 1], k[i:i + 1], v[i:i + 1],
-                                   jnp.int32(int(pos[i])))
+                                   jnp.int32(int(pos[i])), interpret=True)
         np.testing.assert_array_equal(np.asarray(out[i:i + 1]),
                                       np.asarray(row))
 
@@ -236,7 +236,7 @@ def test_decode_attention_scalar_pos_unchanged():
     q = jax.random.normal(k0, (b, 1, h, hd))
     k = jax.random.normal(jax.random.fold_in(k0, 1), (b, t, kv, hd))
     v = jax.random.normal(jax.random.fold_in(k0, 2), (b, t, kv, hd))
-    out = ops.decode_attention(q, k, v, jnp.int32(17))
+    out = ops.decode_attention(q, k, v, jnp.int32(17), interpret=True)
     want = ref.decode_attention(q, k, v, jnp.full((b,), 17, jnp.int32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
@@ -436,3 +436,18 @@ def test_engine_admit_validation_is_not_an_assert(dense_engine):
                            max_new=16, seed=0)
     # (the max_len overflow check is gated off for sliding-window/ssm
     # families like this one — exercised implicitly by full-attention runs)
+
+
+def test_engine_programs_take_params_as_arguments(dense_engine):
+    """The weights reach every jitted engine program as an argument. A
+    closure constant would be compiled into each executable: GBs of host
+    memory per program at published widths."""
+    eng = dense_engine
+    param_bytes = sum(x.nbytes for x in jax.tree.leaves(eng.params))
+    prompts = jnp.zeros((2, 4), jnp.int32)
+    state = jax.eval_shape(lambda: eng.init_slots(2))
+    for lowered in (eng._prefill_jit.lower(eng.params, prompts),
+                    eng._decode_jit.lower(eng.params, state, steps=2),
+                    eng._gen.lower(eng.params, prompts,
+                                   jax.random.PRNGKey(0), max_new=2)):
+        assert len(lowered.as_text()) < param_bytes / 4

@@ -5,7 +5,8 @@ reference bit-for-bit: same prefix, same stable tie order, across random
 masks, duplicate mean-cost ties, and the all-fail / all-succeed edges."""
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.env import feedback
 

@@ -27,6 +27,7 @@ import pytest
 
 from repro.core.policies import PolicyConfig
 from repro.env.llm_profiles import default_rho, paper_pool
+from repro.launch.mesh import make_mesh
 from repro.router import fleet
 
 T = 20
@@ -130,7 +131,7 @@ needs8 = pytest.mark.skipif(
     ((2, 4), ("pod", "data"), 16),
 ])
 def test_sharded_fleet_bit_equal_inprocess(pool, mesh_shape, axes_names, m):
-    mesh = jax.make_mesh(mesh_shape, axes_names)
+    mesh = make_mesh(mesh_shape, axes_names)
     cfg = mixed_cfg(pool, m)
     keys = jax.random.split(jax.random.PRNGKey(2), m)
     sharded = fleet.simulate_fleet(pool, cfg, T=T, keys=keys, mesh=mesh)
@@ -143,7 +144,7 @@ def test_sharded_fleet_nondivisible_falls_back(pool):
     """M=10 on 8 devices: spec_for's divisibility fallback replicates the
     tenant axis, fleet_mesh_axes reports None, and the run still matches
     the reference (it IS the reference path)."""
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     assert fleet.fleet_mesh_axes(10, mesh) is None
     cfg = mixed_cfg(pool, 10)
     keys = jax.random.split(jax.random.PRNGKey(4), 10)
@@ -156,7 +157,7 @@ def test_sharded_fleet_nondivisible_falls_back(pool):
 def test_sharded_resume_bit_equal(pool, tmp_path):
     """Kill-then-resume THROUGH the sharded path reproduces the sharded
     uninterrupted trajectory (checkpointing and shard_map compose)."""
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     m, every, kill, total = 16, 4, 6, 12
     cfg = mixed_cfg(pool, m, T=total)
     keys = jax.random.split(jax.random.PRNGKey(9), m)

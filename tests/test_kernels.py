@@ -29,7 +29,8 @@ def test_flash_attention_causal(b, h, kv, s, d, dtype):
                           (b, kv, s, d), jnp.float32).astype(dtype)
     v = jax.random.normal(jax.random.fold_in(k0, 2),
                           (b, kv, s, d), jnp.float32).astype(dtype)
-    out = ops.flash_attention(q, k, v, causal=True, bq=64, bk=64)
+    out = ops.flash_attention(q, k, v, causal=True, bq=64, bk=64,
+                              interpret=True)
     want = ref.flash_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=tol(dtype))
@@ -43,7 +44,7 @@ def test_flash_attention_sliding_window(window):
     k = jax.random.normal(jax.random.fold_in(k0, 1), (b, kv, s, d))
     v = jax.random.normal(jax.random.fold_in(k0, 2), (b, kv, s, d))
     out = ops.flash_attention(q, k, v, causal=True, window=window,
-                              bq=64, bk=64)
+                              bq=64, bk=64, interpret=True)
     want = ref.flash_attention(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
@@ -54,7 +55,8 @@ def test_flash_attention_non_causal():
     q = jax.random.normal(k0, (b, h, s, d))
     k = jax.random.normal(jax.random.fold_in(k0, 1), (b, kv, s, d))
     v = jax.random.normal(jax.random.fold_in(k0, 2), (b, kv, s, d))
-    out = ops.flash_attention(q, k, v, causal=False, bq=64, bk=64)
+    out = ops.flash_attention(q, k, v, causal=False, bq=64, bk=64,
+                              interpret=True)
     want = ref.flash_attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
@@ -73,7 +75,8 @@ def test_decode_attention(b, h, kv, t, d, pos, dtype):
                            (b, t, kv, d), jnp.float32).astype(dtype)
     vc = jax.random.normal(jax.random.fold_in(k0, 2),
                            (b, t, kv, d), jnp.float32).astype(dtype)
-    out = ops.decode_attention(q, kc, vc, jnp.int32(pos), bk=64)
+    out = ops.decode_attention(q, kc, vc, jnp.int32(pos), bk=64,
+                               interpret=True)
     want = ref.decode_attention(q, kc, vc, jnp.int32(pos))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=tol(dtype))
@@ -93,7 +96,7 @@ def test_ssd_chunk(b, nc, l, h, p, n):
     acum = jnp.cumsum(a, axis=2)
     bm = jax.random.normal(jax.random.fold_in(k0, 2), (b, nc, l, n))
     cm = jax.random.normal(jax.random.fold_in(k0, 3), (b, nc, l, n))
-    y, st = ops.ssd_chunk(xd, acum, bm, cm)
+    y, st = ops.ssd_chunk(xd, acum, bm, cm, interpret=True)
     y2, st2 = ref.ssd_chunk(xd, acum, bm, cm)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y2), atol=1e-4)
     np.testing.assert_allclose(np.asarray(st), np.asarray(st2), atol=1e-4)
@@ -181,9 +184,9 @@ def test_awc_fw_kernel_tie_order_and_positivity():
     assert costs[0, 1] == 1.0
 
 
-def test_awc_fw_ops_dispatch(monkeypatch):
-    """`ops.awc_fw` must agree between the forced-Pallas (interpret) and
-    pure-jnp dispatch paths."""
+def test_awc_fw_ops_dispatch():
+    """`ops.awc_fw` must agree between the Pallas (interpret) and pure-jnp
+    dispatch paths."""
     k0 = jax.random.PRNGKey(7)
     z = jax.random.uniform(k0, (5, 9), jnp.float32)
     mu = jax.random.uniform(jax.random.fold_in(k0, 1), (5, 9), jnp.float32,
@@ -192,23 +195,19 @@ def test_awc_fw_ops_dispatch(monkeypatch):
                               0.01, 0.6)
     lams = jnp.broadcast_to(jnp.asarray([0.0, 0.5, 1.0, 8.0]), (5, 4))
     n = jnp.asarray([1, 2, 3, 4, 9], jnp.int32)
-    monkeypatch.setenv("REPRO_AWC_FW_PALLAS", "0")
     g_plain, c_plain = ops.awc_fw(z, mu, cost, lams, n)
-    monkeypatch.setenv("REPRO_AWC_FW_PALLAS", "1")
-    g_forced, c_forced = ops.awc_fw(z, mu, cost, lams, n)
+    g_forced, c_forced = ops.awc_fw(z, mu, cost, lams, n, interpret=True)
     np.testing.assert_allclose(np.asarray(g_plain), np.asarray(g_forced),
                                atol=2e-6)
     np.testing.assert_allclose(np.asarray(c_plain), np.asarray(c_forced),
                                atol=1e-5)
 
 
-def test_awc_solve_fused_wide_lowering_matches_reference(monkeypatch):
+def test_awc_solve_fused_wide_lowering_matches_reference(tpu_lowering):
     """The AWC relax solve on the fused-kernel wide lowering (awc_fw +
     topn_lp in interpret mode) stays decision-equivalent to the bisect
     reference."""
     from repro.core import relax, rewards as R
-    monkeypatch.setenv("REPRO_TOPN_LP_PALLAS", "1")
-    monkeypatch.setenv("REPRO_AWC_FW_PALLAS", "1")
     rng = np.random.default_rng(3)
     k, n = 7, 3
     mu = jnp.asarray(rng.uniform(0.05, 0.95, k), jnp.float32)
@@ -224,17 +223,16 @@ def test_awc_solve_fused_wide_lowering_matches_reference(monkeypatch):
     assert float(c @ zg) <= rho * 1.01 + 1e-4
 
 
-def test_topn_lp_ops_dispatch(monkeypatch):
-    """`ops.topn_lp` must agree between the forced-Pallas (interpret) and
-    pure-jnp dispatch paths."""
+def test_topn_lp_ops_dispatch():
+    """`ops.topn_lp` must agree between the Pallas (interpret) and pure-jnp
+    dispatch paths."""
     k0 = jax.random.PRNGKey(0)
     score = jax.random.normal(k0, (6, 9), jnp.float32)
     cost = jax.random.uniform(jax.random.fold_in(k0, 1), (6, 9), jnp.float32)
     n = jnp.asarray([1, 2, 3, 4, 5, 9], jnp.int32)
-    monkeypatch.setenv("REPRO_TOPN_LP_PALLAS", "0")
     plain = np.asarray(ops.topn_lp(score, cost, n, equality=True))
-    monkeypatch.setenv("REPRO_TOPN_LP_PALLAS", "1")
-    forced = np.asarray(ops.topn_lp(score, cost, n, equality=True))
+    forced = np.asarray(ops.topn_lp(score, cost, n, equality=True,
+                                    interpret=True))
     np.testing.assert_allclose(plain, forced, atol=1e-6)
 
 

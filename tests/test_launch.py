@@ -72,10 +72,11 @@ import jax, jax.numpy as jnp, json
 from repro.configs.base import get_config
 from repro.configs.shapes import SHAPES
 from repro.launch.dryrun import build_case
+from repro.launch.mesh import make_mesh
 from repro.sharding import use_mesh
 import dataclasses
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = dataclasses.replace(get_config("h2o-danube-3-4b").reduced(),
                           vocab=512, d_model=256, n_heads=4, n_kv_heads=4,
                           head_dim=64, d_ff=512)
@@ -84,8 +85,6 @@ with use_mesh(mesh):
     fn, args, sh = build_case(cfg, shape, mesh, remat=False)
     compiled = jax.jit(fn, in_shardings=sh).lower(*args).compile()
 cost = compiled.cost_analysis()
-if isinstance(cost, list):      # jax 0.4.x: one dict per device program
-    cost = cost[0] if cost else {}
 print(json.dumps({"flops": cost.get("flops", -1),
                   "ndev": mesh.devices.size}))
 """
@@ -93,10 +92,9 @@ print(json.dumps({"flops": cost.get("flops", -1),
 
 def test_small_mesh_lowering_subprocess():
     """A reduced arch lowers+compiles on a real 8-device (2x4) mesh."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
-    env.pop("JAX_PLATFORMS", None)
     out = subprocess.run([sys.executable, "-c", SUBPROC], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -112,6 +110,7 @@ import sys, json, dataclasses
 import jax, jax.numpy as jnp
 from repro.configs.base import get_config
 from repro.models import moe as moe_mod
+from repro.launch.mesh import make_mesh
 from repro.models.layers import init_from_schema
 from repro.sharding import use_mesh
 
@@ -121,7 +120,7 @@ key = jax.random.PRNGKey(0)
 p = init_from_schema(moe_mod.moe_schema(cfg), key, "float32")
 x = jax.random.normal(jax.random.fold_in(key, 1), (4, 8, cfg.d_model))
 y_ref, _ = moe_mod.apply_moe(cfg, p, x)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 with use_mesh(mesh):
     y_ep, _ = jax.jit(lambda p, x: moe_mod.apply_moe_ep(
         cfg, p, x, mesh=mesh, batch_axes=("data",)))(p, x)
@@ -133,10 +132,9 @@ print(json.dumps({"err": err}))
 def test_moe_expert_parallel_matches_spmd_reference():
     """apply_moe_ep (shard_map + all_to_all dispatch, §Perf B2/B3) equals
     the SPMD apply_moe bit-for-bit on a real 2x2 device mesh."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
-    env.pop("JAX_PLATFORMS", None)
     out = subprocess.run([sys.executable, "-c", EP_SUBPROC], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]

@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import relax, rewards as R, rounding
 
@@ -91,6 +92,22 @@ def test_grid_engine_matches_bisect_reference(seed):
             assert int(((zg > 1e-5) & (zg < 1 - 1e-5)).sum()) <= 2
 
 
+@pytest.mark.parametrize("seed", [2766, 3520, 5100, 9269])
+def test_warm_awc_frank_wolfe_matches_bisect(seed):
+    """Instances where a warm-started FW step escaped its carried λ bracket
+    and, bisecting only a few rows, mixed vertices two swaps apart: the
+    objective fell 2.5e-3 to 1.3e-2 below the bisect reference."""
+    mu, c, n, rho = make_instance(seed)
+    mu_j = jnp.array(mu, jnp.float32)
+    c_j = jnp.array(c, jnp.float32)
+    zg = relax.solve_relaxed("awc", mu_j, c_j, n, rho, engine="grid",
+                             fw_warm=True)
+    zb = relax.solve_relaxed("awc", mu_j, c_j, n, rho, engine="bisect")
+    vg = float(R.relaxed_reward("awc", zg, mu_j))
+    vb = float(R.relaxed_reward("awc", zb, mu_j))
+    assert vg >= vb - 1e-5, (vg, vb)
+
+
 @given(instances)
 @settings(max_examples=15, deadline=None)
 def test_grid_static_and_dyn_paths_agree(seed):
@@ -107,10 +124,9 @@ def test_grid_static_and_dyn_paths_agree(seed):
         assert np.array_equal(z_s, z_d), (z_s, z_d)
 
 
-def test_grid_wide_lowering_matches_reference(monkeypatch):
+def test_grid_wide_lowering_matches_reference(tpu_lowering):
     """The accelerator (G-way + Pallas interpret) lowering of the grid
     engine agrees with the bisect reference too."""
-    monkeypatch.setenv("REPRO_TOPN_LP_PALLAS", "1")
     for seed in range(4):
         mu, c, n, rho = make_instance(seed)
         mu_j = jnp.array(mu, jnp.float32)
@@ -124,6 +140,32 @@ def test_grid_wide_lowering_matches_reference(monkeypatch):
             vb = float(R.relaxed_reward(kind, jnp.array(zb), mu_j))
             assert vg >= vb - 1e-5, (kind, seed, vg, vb)
             assert float(c @ zg) <= rho * 1.002 + 1e-5
+
+
+def test_grid_wide_lowering_straddles_adjacent_vertices(tpu_lowering):
+    """The wide lowering brackets with score-form probes (the Pallas
+    kernels) but materializes by crossing thresholds; at this instance the
+    two disagree at the crossing, and without guard probes the final batch
+    mixed vertices two swaps apart (4 fractional coordinates, objective
+    below the bisect reference)."""
+    mu = jnp.array([0.3966922163963318, 0.5911787748336792,
+                    0.5796077251434326, 0.9303721189498901,
+                    0.4144137501716614, 0.20489569008350372,
+                    0.874316930770874, 0.8910447955131531,
+                    0.09536965936422348], jnp.float32)
+    c = jnp.array([0.15250921249389648, 0.5945771336555481,
+                   0.4853133261203766, 0.030987830832600594,
+                   0.26923760771751404, 0.29725217819213867,
+                   0.19643373787403107, 0.5919968485832214,
+                   0.39945176243782043], jnp.float32)
+    n, rho = 5, 1.2675365209579468
+    zg = np.array(relax.solve_relaxed("aic", mu, c, n, rho, engine="grid"))
+    zb = np.array(relax.solve_relaxed("aic", mu, c, n, rho, engine="bisect"))
+    vg = float(R.relaxed_reward("aic", jnp.array(zg), mu))
+    vb = float(R.relaxed_reward("aic", jnp.array(zb), mu))
+    assert vg >= vb - 1e-5, (vg, vb)
+    assert float(np.asarray(c) @ zg) <= rho * 1.002 + 1e-5
+    assert int(((zg > 1e-5) & (zg < 1 - 1e-5)).sum()) <= 2, zg
 
 
 def test_unknown_engine_rejected():
