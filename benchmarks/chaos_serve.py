@@ -36,11 +36,43 @@ fault rate must stay within 2x of fault-free (acceptance, ISSUE 8).
       [--baseline BENCH_chaos.json] [--max-regression 0.25] [--json PATH]
 """
 import argparse
+import dataclasses
 import json
 import os
+import subprocess
 import time
 
-from serve_throughput import VOCAB, build_pool, git_commit
+VOCAB = 64
+
+
+def git_commit():
+    here = os.path.dirname(os.path.abspath(__file__))
+    try:
+        sha = subprocess.check_output(
+            ["git", "rev-parse", "--short", "HEAD"], cwd=here,
+            text=True).strip()
+        dirty = subprocess.run(["git", "diff", "--quiet", "HEAD"],
+                               cwd=here).returncode != 0
+        return sha + ("-dirty" if dirty else "")
+    except Exception:
+        return "unknown"
+
+
+def build_pool(k, *, max_len, arch="h2o-danube-3-4b"):
+    """K untrained reduced-config members of one dense (row-deterministic)
+    family, so both dispatch modes emit identical tokens."""
+    import jax
+    from repro.configs.base import get_config
+    from repro.models import model as M
+    from repro.router.cloud import Replica
+    from repro.serving.engine import Engine
+    cfg = dataclasses.replace(get_config(arch).reduced(), vocab=VOCAB)
+    replicas = []
+    for i in range(k):
+        params = M.init_params(cfg, jax.random.PRNGKey(i))
+        eng = Engine(cfg, params, max_len=max_len, eos_id=0, temperature=0.7)
+        replicas.append(Replica(f"{arch}#{i}", eng, 0.001 * (1 + i)))
+    return replicas
 
 
 def make_services(pcfg, cloud, data, m, mode, *, prompt_len, max_new,

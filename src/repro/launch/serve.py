@@ -9,15 +9,20 @@ It then runs the full local-cloud loop: relax (local) -> round + dispatch
 (cloud) -> generation -> feedback. ``--dispatch continuous`` (the default)
 serves generation through the slot-indexed continuous-batching scheduler;
 ``--tenants M`` steps M local servers against the shared pool so their
-requests coalesce into per-replica decode batches (the throughput case —
-see benchmarks/serve_throughput.py). Callers that want other widths pass
-their own configs (`main(configs=...)`; the CPU example and tests pass
-`.reduced()` ones).
+requests coalesce into per-replica decode batches (the throughput case;
+the served cells of the chip benchmark, `chipbench/`, measure it at
+published widths). Callers that want other widths pass their own configs
+(`main(configs=...)`; the CPU example and tests pass `.reduced()` ones).
+
+After the run each replica's scheduler counters print, the operator's view
+without a profiler: the mean host-clock queue wait of an admitted attempt,
+tokens decoded, tokens over the slot-steps the decode chunks computed, the
+prefill buckets, and the fault counters.
 
 ``--fault-rate`` arms the deterministic chaos layer (serving.faults): a
 seeded fraction of attempts fail (or crash with ``--crash-on-decode``),
 failures feed the bandit as zero-reward observations at the attempted-work
-cost, and per-replica health/quarantine stats print at the end.
+cost, and the terminal failures tenant 0 observed print at the end.
 
   PYTHONPATH=src python -m repro.launch.serve --kind awc --rounds 3 \
       --pool h2o-danube-3-4b,mamba2-780m --tenants 4 --max-len 512 \
@@ -156,6 +161,23 @@ def build_service(args: argparse.Namespace, configs=None):
     return runner, svc, names
 
 
+def counter_lines(names, stats) -> list[str]:
+    """One report line per replica from `ContinuousScheduler.stats`."""
+    lines = []
+    for nm, st in zip(names, stats):
+        wait_ms = 1e3 * st["queue_wait_s"] / max(st["admitted"], 1)
+        per_step = st["tokens_out"] / max(st["slot_steps"], 1)
+        lines.append(
+            f"  {nm}: queue wait {wait_ms:.2f} ms mean over "
+            f"{st['admitted']} admitted, {st['tokens_out']} tokens out, "
+            f"{per_step:.3f} tokens/slot-step, {st['prefill_calls']} "
+            f"prefills ({st['prefill_rows']} rows); failures "
+            f"{st['failures']} retries {st['retries']} rejected "
+            f"{st['rejected']} crashes {st['crashes']} quarantines "
+            f"{st['quarantines']} health {st['health']}")
+    return lines
+
+
 def main(argv=None, configs=None):
     """CLI entry point; ``configs`` as in `build_service`."""
     args = parse_args(argv)
@@ -174,13 +196,13 @@ def main(argv=None, configs=None):
     print(f"mean observed reward {s['mean_observed_reward']:.3f}  "
           f"mean cost {s['mean_cost']:.4f}  violation {s['violation']:.4f}")
     print("selections:", dict(zip(names, svc.local.t_mu.astype(int))))
-    chaos = args.fault_rate > 0 or args.spike_prob > 0
-    if chaos and svc.sched is not None:
-        failed = sum(int(h.failed.sum()) for h in svc.history
-                     if h.failed is not None)
-        print(f"chaos: {failed} terminal failure(s) observed by tenant 0")
-        for nm, st in zip(names, svc.sched.stats()):
-            print(f"  {nm}: {st}")
+    if svc.sched is not None:
+        if args.fault_rate > 0 or args.spike_prob > 0:
+            failed = sum(int(h.failed.sum()) for h in svc.history
+                         if h.failed is not None)
+            print(f"chaos: {failed} terminal failure(s) observed by tenant 0")
+        print("replicas:")
+        print("\n".join(counter_lines(names, svc.sched.stats())))
     return s
 
 

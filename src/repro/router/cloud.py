@@ -27,6 +27,7 @@ from repro.core import rewards as R
 from repro.core import rounding
 from repro.core.policies import PolicyConfig
 from repro.serving.engine import Engine, GenResult
+from repro.spans import span
 
 
 @jax.jit
@@ -79,7 +80,8 @@ class SchedulingCloud:
 
     # ------------------------------------------------------------- rounding
     def select(self, z: np.ndarray, rng: np.random.Generator,
-               available: Optional[np.ndarray] = None) -> np.ndarray:
+               available: Optional[np.ndarray] = None, *,
+               tenant: int = 0) -> np.ndarray:
         """Discretization rounding -> boolean action mask (K,).
 
         The M = 1 case routes through the same jitted `round_batch` program
@@ -93,7 +95,13 @@ class SchedulingCloud:
         rounding, and the rounded action is intersected with the mask so
         the base-matroid padding can never resurrect a dead arm. A None or
         all-True mask takes the exact unmasked path — bit-equal to a run
-        with no fault layer at all."""
+        with no fault layer at all. ``tenant`` only labels the span."""
+        with span("repro.route.select", tenant=tenant) as sp:
+            mask = self._select(z, rng, available)
+            sp.set_metadata(arms=int(mask.sum()))
+        return mask
+
+    def _select(self, z, rng, available) -> np.ndarray:
         z = np.asarray(z, np.float32)
         if available is not None:
             available = np.asarray(available, bool)

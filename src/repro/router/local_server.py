@@ -21,6 +21,7 @@ import numpy as np
 from repro.core import confidence as cb
 from repro.core.policies import PolicyConfig
 from repro.router import fleet
+from repro.spans import span
 
 _update_stats = jax.jit(cb.update_stats)   # elementwise: (1, K) flows through
 
@@ -36,8 +37,9 @@ class FeedbackRecord:
 class LocalServer:
     """Owns user data + bandit statistics; emits relaxed selections."""
 
-    def __init__(self, pcfg: PolicyConfig):
+    def __init__(self, pcfg: PolicyConfig, tenant: int = 0):
         self.pcfg = pcfg
+        self.tenant = tenant
         self._fcfg = fleet.fleet_config([pcfg])
         self.state = fleet.init_tenant_state(1, pcfg.k)
         self.log: list[FeedbackRecord] = []
@@ -70,9 +72,11 @@ class LocalServer:
 
     def relaxed_selection(self) -> np.ndarray:
         """One §4.1 step: UCB/LCB -> relaxed solve -> fractional z̃ (K,)."""
-        self.t = self.t + 1
-        z = fleet.relaxed_batch(self.state.stats, self.state.t, self._fcfg)
-        return np.asarray(z[0])
+        with span("repro.route.relax", tenant=self.tenant):
+            self.t = self.t + 1
+            z = fleet.relaxed_batch(self.state.stats, self.state.t,
+                                    self._fcfg)
+            return np.asarray(z[0])
 
     def record(self, arm: int, reward: float, cost: float) -> None:
         """Eq. (6) incremental update for one observed arm."""

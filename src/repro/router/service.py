@@ -41,6 +41,11 @@ generated tokens that are valid successors under the planted bigram graph —
 a model that has learned the stream scores high, an untrained one scores
 ~branch/vocab. Costs are realized token counts x per-replica price, i.e.
 the paper's statistically-based cost model with real stochastic l_out.
+
+Each tenant's routing of a round (`begin_round`: ``repro.route``, holding
+the local relax and the cloud's select) and each completion's feedback
+(``repro.feedback``) is a host span on the profiler's clock
+(`repro.spans`).
 """
 from __future__ import annotations
 
@@ -53,6 +58,7 @@ from repro.core.policies import PolicyConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.router.cloud import Replica, SchedulingCloud
 from repro.router.local_server import LocalServer
+from repro.spans import span
 
 
 class RoundStateError(RuntimeError):
@@ -101,7 +107,7 @@ class MultiLLMService:
                  scheduler=None, tenant: int = 0, fault_plan=None,
                  health=None, tick_budget: Optional[int] = None):
         self.pcfg = pcfg
-        self.local = LocalServer(pcfg)
+        self.local = LocalServer(pcfg, tenant=tenant)
         self.cloud = cloud
         self.data = data
         self.prompt_len = prompt_len
@@ -167,7 +173,8 @@ class MultiLLMService:
                 or (self._round - 1) % self.batch_size == 0):
             z = self.local.relaxed_selection()
             self._cached_mask = self.cloud.select(z, self.rng,
-                                                  available=avail)
+                                                  available=avail,
+                                                  tenant=self.tenant)
             self._cached_avail = None if avail is None else avail.copy()
         else:
             self.local.t += 1     # the round still elapses
@@ -186,19 +193,21 @@ class MultiLLMService:
         if self._cur is not None:
             raise RoundStateError("previous round not finished")
         self._round += 1
-        mask = self._select_mask()
-        prompts = self.data.batch(self._round)[:, :self.prompt_len]
-        k = self.pcfg.k
-        self._cur = _Round(prompts=prompts, mask=mask, seed=self._round,
-                           rewards=np.zeros(k), observed=np.zeros(k, bool),
-                           costs=np.zeros(k), failed=np.zeros(k, bool),
-                           cascade=list(self._arm_order(mask)))
-        if self.pcfg.kind == "awc":
-            if self._cur.cascade:
-                self._submit(self._cur.cascade.pop(0))
-        else:
-            while self._cur.cascade:
-                self._submit(self._cur.cascade.pop(0))
+        with span("repro.route", tenant=self.tenant, round=self._round):
+            mask = self._select_mask()
+            prompts = self.data.batch(self._round)[:, :self.prompt_len]
+            k = self.pcfg.k
+            self._cur = _Round(prompts=prompts, mask=mask, seed=self._round,
+                               rewards=np.zeros(k),
+                               observed=np.zeros(k, bool), costs=np.zeros(k),
+                               failed=np.zeros(k, bool),
+                               cascade=list(self._arm_order(mask)))
+            if self.pcfg.kind == "awc":
+                if self._cur.cascade:
+                    self._submit(self._cur.cascade.pop(0))
+            else:
+                while self._cur.cascade:
+                    self._submit(self._cur.cascade.pop(0))
 
     def _submit(self, arm: int) -> None:
         from repro.serving.scheduler import Request
@@ -231,14 +240,18 @@ class MultiLLMService:
         if cur is None:
             raise RoundStateError("completion delivered outside a round")
         arm = comp.request.arm
-        cur.inflight -= 1
         ok = getattr(comp, "ok", True)
-        q = self._quality(cur.prompts, comp.result.tokens) if ok else 0.0
-        cost = self.cloud.realized_cost(arm, cur.prompts, comp.result)
-        self._apply_feedback(arm, q, cost, failed=not ok)
-        if (self.pcfg.kind == "awc" and q < self.success_threshold
-                and cur.cascade):
-            self._submit(cur.cascade.pop(0))   # user unsatisfied: next arm
+        with span("repro.feedback", tenant=self.tenant, arm=arm,
+                  rid=comp.request.rid, ok=ok) as sp:
+            cur.inflight -= 1
+            q = self._quality(cur.prompts, comp.result.tokens) if ok else 0.0
+            cost = self.cloud.realized_cost(arm, cur.prompts, comp.result)
+            self._apply_feedback(arm, q, cost, failed=not ok)
+            cascaded = (self.pcfg.kind == "awc" and q < self.success_threshold
+                        and bool(cur.cascade))
+            if cascaded:
+                self._submit(cur.cascade.pop(0))  # user unsatisfied: next arm
+            sp.set_metadata(cascaded=cascaded)
 
     def finish_round(self) -> RoundLog:
         cur = self._cur

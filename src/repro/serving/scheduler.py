@@ -44,11 +44,20 @@ outstanding request is force-failed — so it provably terminates under any
 fault pattern. With no plan and default policy every one of these paths is
 dormant and the scheduler's decisions are bit-identical to the fault-free
 implementation.
+
+Observability: each tick, prefill bucket, decode dispatch and harvest is a
+host span on the profiler's clock (`repro.spans`: ``repro.tick`` holding
+``repro.admit``, ``repro.decode`` and ``repro.harvest``), and each runner
+keeps cumulative work counters — attempts admitted and their host-clock
+queue wait (measured from when the attempt was queued, again on a retry),
+prefill buckets and rows, decode chunks and slot-steps, tokens decoded —
+read through `ContinuousScheduler.stats`.
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -59,6 +68,7 @@ import numpy as np
 from repro.serving.engine import Engine, GenResult, SlotState, _row_keys
 from repro.serving.faults import (EngineCrash, FaultDraw, FaultPlan, Health,
                                   HealthPolicy, NO_FAULT)
+from repro.spans import span
 
 _RID = itertools.count()
 
@@ -95,6 +105,7 @@ class _Pending:
     draw: FaultDraw
     submit_tick: int                  # deadline epoch for this attempt
     not_before: int                   # backoff / latency-spike gate
+    queued_at: float                  # host clock when this attempt queued
 
 
 @dataclasses.dataclass
@@ -143,6 +154,13 @@ class ReplicaRunner:
         self.n_rejected = 0       # dropped without retry (quarantine/abort)
         self.n_crashes = 0
         self.n_quarantines = 0
+        # work accounting, cumulative (`ContinuousScheduler.stats`)
+        self.n_admitted = 0       # attempts admitted into slots
+        self.queue_wait_s = 0.0   # host seconds those attempts queued
+        self.n_prefill_calls = 0  # prefill buckets
+        self.n_prefill_rows = 0
+        self.n_decode_chunks = 0  # each computes n_slots x chunk slot-steps
+        self.n_tokens_out = 0     # tokens decoded, as harvested
 
     @property
     def busy(self) -> bool:
@@ -164,7 +182,8 @@ class ReplicaRunner:
             if self.fault_plan else NO_FAULT
         self.pending.append(_Pending(req=req, fix=fix, attempt=1, draw=draw,
                                      submit_tick=self.tick,
-                                     not_before=self.tick + draw.spike))
+                                     not_before=self.tick + draw.spike,
+                                     queued_at=time.perf_counter()))
 
     # ----------------------------------------------------- health machine
     def _set_health(self, state: Health) -> None:
@@ -235,7 +254,8 @@ class ReplicaRunner:
             self.pending.append(_Pending(
                 req=ent.req, fix=ent.fix, attempt=nxt, draw=draw,
                 submit_tick=self.tick,
-                not_before=self.tick + backoff + draw.spike))
+                not_before=self.tick + backoff + draw.spike,
+                queued_at=time.perf_counter()))
             return None
         return Completion(ent.req, self._fail_result(ent.req, n_out),
                           ok=False, error=why, attempts=ent.attempt)
@@ -332,28 +352,38 @@ class ReplicaRunner:
                 bucket.append(ent)
             if not bucket:
                 return               # head request doesn't fit yet
-            slots = np.asarray([self._free.pop() for _ in range(rows)])
-            lg, cache_slice = self.engine.prefill(
-                np.concatenate([e.req.prompts for e in bucket], axis=0))
-            rkeys = jnp.concatenate([
-                _row_keys(jax.random.PRNGKey(e.req.seed),
-                          e.req.prompts.shape[0])
-                for e in bucket])
-            max_new = np.concatenate([
-                np.full(e.req.prompts.shape[0], e.req.max_new, np.int32)
-                for e in bucket])
-            self.state = self.engine.admit(
-                self.state, slots, lg, cache_slice, prompt_len=s,
-                max_new=max_new, rkeys=rkeys)
-            ofs = 0
-            for ent in bucket:
-                b = ent.req.prompts.shape[0]
-                self.resident[ent.req.rid] = _Resident(
-                    req=ent.req, slots=slots[ofs:ofs + b], fix=ent.fix,
-                    attempt=ent.attempt, draw=ent.draw,
-                    submit_tick=ent.submit_tick, admit_tick=self.tick,
-                    n_out_seen=np.zeros(b, np.int32))
-                ofs += b
+            now = time.perf_counter()
+            waits = [now - e.queued_at for e in bucket]
+            self.n_admitted += len(bucket)
+            self.queue_wait_s += sum(waits)
+            self.n_prefill_calls += 1
+            self.n_prefill_rows += rows
+            with span("repro.admit", replica=self.replica_ix,
+                      requests=len(bucket), rows=rows, prompt_len=s,
+                      wait_us=1e6 * sum(waits),
+                      wait_max_us=1e6 * max(waits)):
+                slots = np.asarray([self._free.pop() for _ in range(rows)])
+                lg, cache_slice = self.engine.prefill(
+                    np.concatenate([e.req.prompts for e in bucket], axis=0))
+                rkeys = jnp.concatenate([
+                    _row_keys(jax.random.PRNGKey(e.req.seed),
+                              e.req.prompts.shape[0])
+                    for e in bucket])
+                max_new = np.concatenate([
+                    np.full(e.req.prompts.shape[0], e.req.max_new, np.int32)
+                    for e in bucket])
+                self.state = self.engine.admit(
+                    self.state, slots, lg, cache_slice, prompt_len=s,
+                    max_new=max_new, rkeys=rkeys)
+                ofs = 0
+                for ent in bucket:
+                    b = ent.req.prompts.shape[0]
+                    self.resident[ent.req.rid] = _Resident(
+                        req=ent.req, slots=slots[ofs:ofs + b], fix=ent.fix,
+                        attempt=ent.attempt, draw=ent.draw,
+                        submit_tick=ent.submit_tick, admit_tick=self.tick,
+                        n_out_seen=np.zeros(b, np.int32))
+                    ofs += b
 
     # ------------------------------------------------------------- faults
     def _expire(self) -> List[Completion]:
@@ -404,18 +434,31 @@ class ReplicaRunner:
     def _harvest(self) -> List[Completion]:
         if not self.resident:
             return []
+        with span("repro.harvest", replica=self.replica_ix) as sp:
+            comps, tokens = self._harvest_slots()
+            sp.set_metadata(done=len(comps), tokens=tokens)
+        return comps
+
+    def _harvest_slots(self) -> Tuple[List[Completion], int]:
+        """Pull the slot arrays, complete every finished request and
+        release its slots; also returns the tokens decoded since the last
+        harvest."""
         step = np.asarray(self.state.step)
         fin = np.asarray(self.state.finished)
         cap = np.asarray(self.state.max_new)
         n_out = np.asarray(self.state.n_out)
         # progress snapshot: after a crash the slot state is gone, so the
         # attempted-work cost of the lost requests comes from here
+        tokens = 0
         for r in self.resident.values():
-            r.n_out_seen = n_out[r.slots].copy()
+            seen = n_out[r.slots]
+            tokens += int((seen - r.n_out_seen).sum())
+            r.n_out_seen = seen
+        self.n_tokens_out += tokens
         done = [rid for rid, r in self.resident.items()
                 if (fin[r.slots] | (step[r.slots] >= cap[r.slots])).all()]
         if not done:
-            return []
+            return [], tokens
         out = np.asarray(self.state.out)
         lp = np.asarray(self.state.lp_sum)
         comps = []
@@ -439,7 +482,7 @@ class ReplicaRunner:
             comps.append(Completion(r.req, res, attempts=r.attempt))
         self.state = self.engine.release(self.state, np.asarray(freed))
         self._free.extend(freed)
-        return comps
+        return comps, tokens
 
     # --------------------------------------------------------------- step
     def step(self) -> List[Completion]:
@@ -447,6 +490,11 @@ class ReplicaRunner:
         fault layer around it (quarantine rejection, injected/real crash
         recovery, deadline + injected-failure expiry)."""
         self.tick += 1
+        with span("repro.tick", replica=self.replica_ix, tick=self.tick,
+                  resident_rows=self.n_slots - len(self._free)):
+            return self._tick()
+
+    def _tick(self) -> List[Completion]:
         self._health_tick()
         if self.health_state is Health.QUARANTINED:
             # purge the work caught by the outage; hold later submissions
@@ -458,10 +506,19 @@ class ReplicaRunner:
             comps += self._expire()
             if self.resident:
                 self._maybe_injected_crash()
-                self.state = self.engine.decode_chunk(self.state, self.chunk)
+                self._decode()
         except Exception as err:      # crash containment: rebuild + requeue
             return comps + self._recover(err)
         return comps + self._harvest()
+
+    def _decode(self) -> None:
+        """Dispatch one decode chunk over every slot."""
+        live = sum(int(np.count_nonzero(r.n_out_seen < r.req.max_new))
+                   for r in self.resident.values())
+        self.n_decode_chunks += 1
+        with span("repro.decode", replica=self.replica_ix,
+                  slots=self.n_slots, steps=self.chunk, live_rows=live):
+            self.state = self.engine.decode_chunk(self.state, self.chunk)
 
 
 class ContinuousScheduler:
@@ -488,12 +545,22 @@ class ContinuousScheduler:
         unavailable arms out of selection and renormalizes z̃."""
         return np.asarray([r.available for r in self.runners], bool)
 
-    def stats(self) -> List[Dict[str, int]]:
-        """Per-runner chaos accounting (benchmarks + launch reporting)."""
+    def stats(self) -> List[Dict[str, object]]:
+        """Per-runner accounting (benchmarks + launch reporting), all
+        cumulative over the runner's life: the chaos counters, and the work
+        counters — attempts admitted and the host seconds they queued,
+        prefill buckets and their rows, decode chunks and the slot-steps
+        they computed (slots x steps, live or not), tokens decoded."""
         return [{"failures": r.n_failures, "retries": r.n_retries,
                  "rejected": r.n_rejected, "crashes": r.n_crashes,
                  "quarantines": r.n_quarantines,
-                 "health": r.health_state.value}
+                 "health": r.health_state.value,
+                 "admitted": r.n_admitted, "queue_wait_s": r.queue_wait_s,
+                 "prefill_calls": r.n_prefill_calls,
+                 "prefill_rows": r.n_prefill_rows,
+                 "decode_chunks": r.n_decode_chunks,
+                 "slot_steps": r.n_decode_chunks * r.n_slots * r.chunk,
+                 "tokens_out": r.n_tokens_out}
                 for r in self.runners]
 
     def _fire(self, comp: Completion, sink: List[Completion]) -> None:

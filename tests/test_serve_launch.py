@@ -35,6 +35,29 @@ def test_build_service_uses_the_given_configs():
     assert [r.n_slots for r in svc.sched.runners] == [serve.SLOTS] * 2
 
 
+def test_report_reads_the_scheduler_counters():
+    cfgs = reduced_bf16()
+    args = serve.parse_args(["--tenants", "2", "--max-len", "32",
+                             "--kind", "suc"])
+    runner, svc, names = serve.build_service(args, cfgs)
+    runner.run(1)
+    stats = svc.sched.stats()
+    lines = serve.counter_lines(names, stats)
+    assert len(lines) == 2
+    for nm, st, line in zip(names, stats, lines):
+        # SUC with n = k: every tenant asks every member once a round
+        assert st["admitted"] == 2
+        assert st["tokens_out"] == sum(
+            int(c.result.out_lens.sum()) for c in runner.last_completions
+            if c.request.arm == names.index(nm))
+        wait_ms = 1e3 * st["queue_wait_s"] / 2
+        per_step = st["tokens_out"] / st["slot_steps"]
+        assert line.startswith(f"  {nm}: queue wait {wait_ms:.2f} ms mean "
+                               f"over 2 admitted, {st['tokens_out']} tokens "
+                               f"out, {per_step:.3f} tokens/slot-step")
+        assert "failures 0 retries 0" in line
+
+
 def test_chip_smoke_served_phase_on_cpu(monkeypatch):
     monkeypatch.syspath_prepend(ROOT)
     import chip_smoke
