@@ -23,7 +23,17 @@ from repro.core.policies import PolicyConfig
 from repro.router import fleet
 from repro.spans import span
 
-_update_stats = jax.jit(cb.update_stats)   # elementwise: (1, K) flows through
+
+@jax.jit
+def _record(stats, arm, reward, cost):
+    """One observation's Eq.-(6) update as one compiled dispatch: the
+    one-hot rows are built inside the trace, so a traced int32 ``arm`` and
+    float32 ``reward``/``cost`` share one executable per K."""
+    k = stats["t_mu"].shape[-1]
+    row = jnp.zeros((1, k), jnp.float32)
+    return cb.update_stats(stats, row.at[0, arm].set(1.0),
+                           row.at[0, arm].set(reward),
+                           row.at[0, arm].set(cost))
 
 
 @dataclasses.dataclass
@@ -45,13 +55,26 @@ class LocalServer:
         self.log: list[FeedbackRecord] = []
 
     # ------------------------------------------------------------ statistics
+    # The round counter lives twice: `_t` on the host, read without waiting
+    # on the device, and `state.t` for the jitted solve. Every write goes
+    # through the `state` or `t` setter, which keep the two equal.
+    @property
+    def state(self) -> fleet.TenantState:
+        return self._state
+
+    @state.setter
+    def state(self, value: fleet.TenantState) -> None:
+        self._state = value
+        self._t = int(np.asarray(value.t)[0])
+
     @property
     def t(self) -> int:
-        return int(self.state.t[0])
+        return self._t
 
     @t.setter
     def t(self, value: int) -> None:
-        self.state = self.state._replace(
+        self._t = int(value)
+        self._state = self._state._replace(
             t=jnp.full((1,), float(value), jnp.float32))
 
     @property
@@ -79,11 +102,9 @@ class LocalServer:
             return np.asarray(z[0])
 
     def record(self, arm: int, reward: float, cost: float) -> None:
-        """Eq. (6) incremental update for one observed arm."""
-        k = self.pcfg.k
-        obs = jnp.zeros((1, k), jnp.float32).at[0, arm].set(1.0)
-        x = jnp.zeros((1, k), jnp.float32).at[0, arm].set(float(reward))
-        y = jnp.zeros((1, k), jnp.float32).at[0, arm].set(float(cost))
-        self.state = self.state._replace(
-            stats=_update_stats(self.state.stats, obs, x, y))
-        self.log.append(FeedbackRecord(self.t, arm, reward, cost))
+        """Eq. (6) incremental update for one observed arm: one dispatch,
+        no device read (fixed dtypes keep one executable per K)."""
+        self._state = self._state._replace(
+            stats=_record(self._state.stats, np.int32(arm),
+                          np.float32(reward), np.float32(cost)))
+        self.log.append(FeedbackRecord(self._t, arm, reward, cost))
