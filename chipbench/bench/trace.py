@@ -3,15 +3,17 @@
 The JAX profiler writes an `.xplane.pb`; `jax.profiler.ProfileData` reads
 it. Device planes (`/device:TPU:<n>`) carry one event per executed program
 (line "XLA Modules") and per operation inside it (line "XLA Ops"); the host
-plane carries the benchmark's own spans (`jax.profiler.TraceAnnotation`).
-All timestamps share one clock.
+plane carries the benchmark's own spans (`chipbench.*`) and the program's
+(`repro.*`, with their counts as the event's stats), both written by
+`jax.profiler.TraceAnnotation`. All timestamps share one clock.
 
 The reduction:
   busy      union of the device's operation intervals inside the window;
   idle      1 - busy / window;
   programs  device seconds per program name (the breakdown's device_ops);
-  gaps      the idle intervals, each charged to the innermost benchmark
-            host span that covers its midpoint (the breakdown's idle_gaps).
+  gaps      the idle intervals, each charged to the innermost host span,
+            the benchmark's or the program's, that covers its midpoint
+            (the breakdown's idle_gaps).
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ import numpy as np
 
 WINDOW_SPAN = "chipbench.window"
 SPAN_PREFIX = "chipbench."
+PROGRAM_PREFIX = "repro."
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -50,7 +53,7 @@ class Trace:
     busy: Dict[str, np.ndarray]       # device plane -> (N, 2) op intervals
     modules: Dict[str, List[Event]]   # device plane -> program events
     calls: List[Event]                # custom-call operations (kernels)
-    spans: List[Event]                # the benchmark's host spans
+    spans: List[Event]                # host spans: chipbench.* and repro.*
     window: Tuple[float, float]       # ns, from the window span
 
     @property
@@ -138,10 +141,17 @@ def modules_named(trace: Trace, name: str) -> List[Event]:
             if e.end > lo and e.start < hi and module_name(e.name) == name]
 
 
+def spans_in_window(trace: Trace, name: str) -> List[Event]:
+    """The host spans of one name that start inside the window."""
+    lo, hi = trace.window
+    return [s for s in trace.spans if s.name == name and lo <= s.start < hi]
+
+
 def gap_attribution(trace: Trace) -> Dict[str, float]:
-    """Idle seconds inside the window, by the innermost benchmark span that
-    covers each gap's midpoint ("none" where no span does). Averaged over
-    the devices traced."""
+    """Idle seconds inside the window, by the innermost host span that
+    covers each gap's midpoint ("none" where no span does), the benchmark's
+    named without its `chipbench.` prefix. Averaged over the devices
+    traced."""
     tot: Dict[str, float] = collections.Counter()
     by_name: Dict[str, List[Event]] = collections.defaultdict(list)
     for s in trace.spans:
@@ -203,8 +213,9 @@ _KEEP_STATS = ("hlo_module", "hlo_op", "long_name", "program_id")
 CALL = re.compile(r"custom[-_]call|pallas|tpu_custom", re.I)
 
 
-def _event(e, stats: bool) -> Event:
-    kept = {k: v for k, v in e.stats if k in _KEEP_STATS} if stats else {}
+def _event(e, keep=()) -> Event:
+    """``keep``: the names of the stats to keep, or None for all."""
+    kept = {k: v for k, v in e.stats if keep is None or k in keep}
     return Event(e.name, float(e.start_ns),
                  float(e.start_ns) + float(e.duration_ns), kept)
 
@@ -222,11 +233,10 @@ def load(path: str) -> Trace:
                     for e in line.events:
                         rows.append((e.start_ns, e.start_ns + e.duration_ns))
                         if CALL.search(e.name):
-                            calls.append(_event(e, True))
+                            calls.append(_event(e, _KEEP_STATS))
                     ops = np.asarray(rows, np.float64).reshape(-1, 2)
                 elif line.name == MODULES_LINE:
-                    modules[plane.name] = [_event(e, False)
-                                           for e in line.events]
+                    modules[plane.name] = [_event(e) for e in line.events]
             if ops is not None and len(ops):
                 busy[plane.name] = ops
             elif modules.get(plane.name):
@@ -234,8 +244,8 @@ def load(path: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
-                        spans.append(_event(e, False))
+                    if e.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX)):
+                        spans.append(_event(e, None))
     win = [s for s in spans if s.name == WINDOW_SPAN]
     if not win:
         raise RuntimeError(f"the trace holds no {WINDOW_SPAN} span")

@@ -15,8 +15,9 @@ reference reads those same bfloat16 values, cast to float32.
 activations, the attention's q, k and v) rounded to float8 e4m3, the
 nearest precision below the configuration's bfloat16.
 
-The work functions at the end give the FLOPs and bytes one token needs, for
-the per-layer metrics `decode_roofline` and `served_mfu`.
+The work functions give the FLOPs and bytes one token needs, for the
+per-layer metrics `decode_roofline` and `served_mfu`; `tiny_arch` at the end
+gives each member's sizes for the CPU tests.
 """
 from __future__ import annotations
 
@@ -262,3 +263,18 @@ def decode_step_bytes(a: Dict, ctxs: Sequence[int], wbytes: int = 2) -> float:
     state = nl * ssm_heads(a) * a["ssm_head_dim"] * n * 4
     conv = nl * (CONV_WIDTH - 1) * (di + 2 * n) * wbytes
     return weights + rows * 2.0 * (state + conv)
+
+
+# ----------------------------------------------------------- tiny
+TINY_ARCH = {
+    "dense": {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
+              "head_dim": 16, "d_ff": 128, "vocab": 512},
+    "ssm": {"n_layers": 2, "d_model": 64, "ssm_state": 16,
+            "ssm_head_dim": 16, "ssm_chunk": 8, "vocab": 512},
+}
+
+
+def tiny_arch(arch: Dict) -> Dict:
+    """The keys to override in one member's ``arch`` for the CPU tests
+    (`chipbench/tests/tiny.py`); the cells run at published sizes."""
+    return dict(TINY_ARCH[arch["family"]])

@@ -52,6 +52,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from bench import cells
 from bench.stream import QueryStream, StreamConfig, quality
 
 
@@ -76,6 +77,14 @@ class Driver:
 
         cfg, tr = self.cfg, self.tr
         names = {f.name for f in dataclasses.fields(ArchConfig)}
+        for m in self.members:
+            unknown = sorted(set(m["arch"]) - names)
+            if unknown:
+                # dropped, they would serve another model than the
+                # configuration and its reference describe
+                raise cells.CellError(
+                    f"member {m['name']}: arch keys {unknown} are not "
+                    "fields of the program's ArchConfig")
         self.params, replicas = [], []
         for m in self.members:
             key = jax.random.PRNGKey(int(self.rng.integers(2 ** 31 - 1)))
@@ -83,8 +92,7 @@ class Driver:
                                           jnp.dtype(cfg["dtype"]))
             jax.block_until_ready(params)
             self.params.append(params)
-            arch = ArchConfig(**{k: v for k, v in m["arch"].items()
-                                 if k in names})
+            arch = ArchConfig(**m["arch"])
             eng = Engine(arch, params, max_len=int(cfg["max_len"]),
                          eos_id=int(cfg["eos_id"]),
                          temperature=float(cfg["temperature"]),

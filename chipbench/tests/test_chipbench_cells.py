@@ -1,5 +1,6 @@
 """BENCHMARK.json: every cell resolves to its files, and the file keeps the
-benchmark's own rules on names, units, sources and bounds."""
+benchmark's own rules on names, units, sources and bounds; each cell's tiny
+sizes for the CPU tests, and the pool members' keys against the program's."""
 import json
 import os
 import re
@@ -7,7 +8,7 @@ import re
 import pytest
 
 from bench import cells
-from tiny import bench
+from tiny import bench, tiny
 
 BENCH = cells.load_benchmark()
 WITH_HELD_OUT = bench()
@@ -83,3 +84,94 @@ def test_every_checked_number_has_a_limit():
         if c.config["driver"] == "pool":
             for m in c.config["members"]:
                 assert "gap." + m["name"] in lim
+
+
+# ------------------------------------------------------ tiny sizes
+DENSE_TINY = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
+              "head_dim": 16, "d_ff": 128, "vocab": 512}
+SSM_TINY = {"n_layers": 2, "d_model": 64, "ssm_state": 16,
+            "ssm_head_dim": 16, "ssm_chunk": 8, "vocab": 512}
+POOL_TRAFFIC = {"tenants": 2, "prompt_len": 8, "max_new": 8,
+                "stream_vocab": 256, "rows": 2, "check_rounds": 1,
+                "warm_rounds": 1}
+
+
+@pytest.mark.parametrize("cell",
+                         [w["name"] for w in WITH_HELD_OUT["workloads"]])
+def test_tiny_sizes_of_every_cell(cell):
+    c = cells.resolve(cell, WITH_HELD_OUT)
+    t = tiny(c)
+    if c.config["driver"] == "fleet":
+        assert t.config["tenants"] == 24
+        assert t.traffic["rounds_per_call"] == 4
+        return
+    for full, small in zip(c.config["members"], t.config["members"]):
+        want = dict(full["arch"])
+        want.update({"dense": DENSE_TINY, "ssm": SSM_TINY}[
+            full["arch"]["family"]])
+        assert small["arch"] == want
+    assert (t.config["slots"], t.config["max_len"], t.config["chunk"]) == \
+        (8, 64, 4)
+    assert {k: t.traffic[k] for k in POOL_TRAFFIC} == POOL_TRAFFIC
+    assert c.config["members"][0]["arch"]["d_model"] == 3840   # a copy
+
+
+def pool_cell(family):
+    return cells.Cell(
+        name="new-pool", chips=1, config_name="new-pool",
+        traffic_name="t", traffic={},
+        config={"driver": "pool", "members": [
+            {"name": "m", "arch": {"family": family, "d_model": 2688,
+                                   "n_groups": 8}}]},
+        end_to_end=[], per_layer=[])
+
+
+def test_tiny_sizes_of_a_new_family_come_from_its_reference(
+        tmp_path, monkeypatch):
+    ref = tmp_path / "new-pool.py"
+    ref.write_text("def tiny_arch(arch):\n"
+                   "    return {'d_model': 64, 'n_groups': 2}\n")
+    monkeypatch.setattr(cells, "reference_path",
+                        lambda name: str(tmp_path / (name + ".py")))
+    t = tiny(pool_cell("hybrid-moe"))
+    assert t.config["members"][0]["arch"] == \
+        {"family": "hybrid-moe", "d_model": 64, "n_groups": 2}
+
+
+def test_a_reference_without_tiny_arch_is_refused(tmp_path, monkeypatch):
+    (tmp_path / "new-pool.py").write_text("X = 1\n")
+    monkeypatch.setattr(cells, "reference_path",
+                        lambda name: str(tmp_path / (name + ".py")))
+    with pytest.raises(cells.CellError, match=r"new-pool\.py.*tiny_arch"):
+        tiny(pool_cell("dense"))
+
+
+# ------------------------------------------------- pool members' keys
+def arch_fields():
+    import dataclasses
+
+    from repro.configs.base import ArchConfig
+    return {f.name for f in dataclasses.fields(ArchConfig)}
+
+
+def test_pool_members_use_only_the_program_s_arch_keys():
+    pools = []
+    for c in WITH_HELD_OUT["configs"]:
+        with open(os.path.join(cells.ROOT, c["file"])) as f:
+            config = json.load(f)
+        if config["driver"] == "pool":
+            pools.append(config)
+    assert pools
+    for config in pools:
+        for m in config["members"]:
+            assert set(m["arch"]) <= arch_fields(), m["name"]
+
+
+def test_pool_driver_refuses_an_unknown_arch_key():
+    from bench import harness
+    c = tiny(cells.resolve("pool-awc-short", BENCH))
+    c.config["members"][1]["arch"]["n_groups"] = 8
+    d = harness.make_driver(c, 7)
+    with pytest.raises(cells.CellError, match="mamba2-780m.*n_groups"):
+        d._build()
+    assert not hasattr(d, "params")       # refused before any weight

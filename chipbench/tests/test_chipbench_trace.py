@@ -1,5 +1,5 @@
 """The trace-to-metrics reduction on a constructed trace, with every number
-worked out by hand."""
+worked out by hand, and the program's spans kept from a recorded one."""
 import pytest
 
 from bench import device, kernels, trace
@@ -99,3 +99,69 @@ def test_kernel_absent_reads_nothing():
     t = window_trace()
     t.calls = t.calls[1:]
     assert kernels.roofline(t, {"arms": 9, "peaks": V5E}, "topn_lp") is None
+
+
+# ------------------------------------------------- the program's spans
+def test_load_keeps_the_program_spans_with_their_counts():
+    import jax
+    from jax.profiler import TraceAnnotation
+    rec = trace.Recorder()
+    rec.start()
+    try:
+        with TraceAnnotation(trace.WINDOW_SPAN):
+            with TraceAnnotation("repro.x", wait_us=1500, requests=3):
+                jax.block_until_ready(jax.jit(lambda a: a + 1)(1.0))
+            with TraceAnnotation("other.y", requests=9):
+                pass
+    finally:
+        rec.stop()
+    t = rec.load()
+    x, = [s for s in t.spans if s.name == "repro.x"]
+    assert x.stats["wait_us"] == 1500 and x.stats["requests"] == 3
+    assert not [s for s in t.spans if s.name == "other.y"]
+    assert t.window[0] <= x.start <= x.end <= t.window[1]
+    assert trace.spans_in_window(t, "repro.x") == [x]
+
+
+def test_gaps_charged_to_an_inner_program_span():
+    # step.a 0-100 holds repro.tick 10-90, which holds repro.harvest 40-60;
+    # idle 20-25 (midpoint in the tick only) and 30-65 (in the harvest)
+    spans = [ev("chipbench.window", 0, 100), ev("chipbench.step.a", 0, 100),
+             ev("repro.tick", 10, 90), ev("repro.harvest", 40, 60)]
+    busy = trace.intervals([ev("f", 0, 20), ev("f", 25, 30),
+                            ev("f", 65, 100)])
+    t = Trace(busy={"/device:TPU:0": busy}, modules={}, calls=[],
+              spans=spans, window=(0.0, 100 * MS))
+    assert trace.gap_attribution(t) == pytest.approx(
+        {"repro.tick": 0.005, "repro.harvest": 0.035})
+
+
+def spans_trace():
+    """A 100 ms window. Spans that start before it (admit at -5) or at its
+    end (feedback at 100) are not the window's."""
+    spans = [ev("chipbench.window", 0, 100),
+             ev("repro.admit", -5, 2, wait_us=9999, requests=7),
+             ev("repro.admit", 10, 12, wait_us=3000, requests=2),
+             ev("repro.admit", 40, 41, wait_us=1000, requests=2),
+             ev("repro.admit", 99, 105, wait_us=5000, requests=1),
+             ev("repro.feedback", 20, 21.5), ev("repro.feedback", 50, 52.5),
+             ev("repro.feedback", 100, 130),
+             ev("repro.harvest", 30, 33), ev("repro.harvest", 60, 65)]
+    return Trace(busy={}, modules={}, calls=[], spans=spans,
+                 window=(0.0, 100 * MS))
+
+
+@pytest.mark.parametrize("name, want", [
+    # (3000 + 1000 + 5000) us over 5 requests
+    ("queue_wait_ms", 1.8),
+    # (1.5 + 2.5) / 2 ms
+    ("feedback_ms_per_completion", 2.0),
+    # (3 + 5) / 2 ms
+    ("harvest_ms_per_tick", 4.0)])
+def test_span_readers_by_hand(name, want):
+    from bench import cells
+    mod = cells.load_module(cells.metric_path(name), name)
+    assert mod.read(spans_trace(), {}) == pytest.approx(want)
+    t = spans_trace()
+    t.spans = t.spans[:1]
+    assert mod.read(t, {}) is None

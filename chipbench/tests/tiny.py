@@ -1,6 +1,9 @@
 """Tiny versions of the benchmark's cells for the CPU tests: the same
 drivers, configuration structure and traffic, at widths and counts a test
-run can hold. Widths are cut here only; the cells run at published sizes."""
+run can hold. Widths are cut here only; the cells run at published sizes.
+A pool member's tiny sizes come from its configuration's reference
+(`tiny_arch` in `chipbench/configs/<config>.py`), so a configuration of a
+new family brings its own."""
 import copy
 import json
 import os
@@ -10,13 +13,6 @@ from bench import cells
 HELD_OUT = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "held_out.json")
 
-TINY_ARCH = {
-    "dense": {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
-              "head_dim": 16, "d_ff": 128, "vocab": 512},
-    "ssm": {"n_layers": 2, "d_model": 64, "ssm_state": 16,
-            "ssm_head_dim": 16, "ssm_chunk": 8, "vocab": 512},
-}
-
 
 def tiny(cell: cells.Cell) -> cells.Cell:
     c = copy.deepcopy(cell)
@@ -24,8 +20,14 @@ def tiny(cell: cells.Cell) -> cells.Cell:
         c.config["tenants"] = 24
         c.traffic["rounds_per_call"] = 4
     else:
+        path = cells.reference_path(c.config_name)
+        ref = cells.load_module(path, c.config_name)
+        if not hasattr(ref, "tiny_arch"):
+            raise cells.CellError(
+                f"{os.path.relpath(path, cells.ROOT)} defines no tiny_arch"
+                "(arch) for the CPU tests")
         for m in c.config["members"]:
-            m["arch"].update(TINY_ARCH[m["arch"]["family"]])
+            m["arch"].update(ref.tiny_arch(m["arch"]))
         c.config.update(slots=8, max_len=64, chunk=4)
         c.traffic.update(tenants=2, prompt_len=8, max_new=8,
                          stream_vocab=256, rows=2, check_rounds=1,
