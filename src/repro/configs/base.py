@@ -5,7 +5,8 @@ are plain frozen dataclasses so they can be hashed into jit static args, and
 carry enough structure for all six families:
 
   dense | moe | hybrid (mamba2 + shared attention) | audio (enc-dec) |
-  vlm (M-RoPE decoder) | ssm (mamba2)
+  vlm (M-RoPE decoder) | ssm (mamba2) |
+  hybrid_moe (Mamba-2, expert and attention layers in a ``layer_pattern``)
 
 ``reduced()`` returns the CPU-smoke variant of the same family (2 layers,
 d_model <= 512, <= 4 experts) used by tests; the full configs are exercised
@@ -22,6 +23,7 @@ from typing import Optional, Tuple
 class ArchConfig:
     name: str
     family: str                      # dense | moe | hybrid | audio | vlm | ssm
+                                     # | hybrid_moe
     n_layers: int
     d_model: int
     n_heads: int                     # 0 for attention-free
@@ -45,19 +47,33 @@ class ArchConfig:
     # "d_ff" shards the expert hidden dim (ZeRO-style weight all-gather).
     # See EXPERIMENTS.md §Perf iteration B1.
     moe_fsdp_dim: str = "d_ff"
+    # held-share expert layer (hybrid_moe, `moe.apply_held_moe`): the
+    # router scores `n_router_experts` experts; this model holds
+    # `n_experts` of them, [expert_offset, expert_offset + n_experts), and
+    # computes their part, plus a shared expert of `shared_expert_d_ff`
+    n_router_experts: int = 0        # 0: n_experts (all held)
+    expert_offset: int = 0
+    routed_scale: float = 1.0        # routed weights' scale after top_k
+    shared_expert_d_ff: int = 0
     # --- SSM (mamba2) ---
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_chunk: int = 256
+    ssm_n_heads: int = 0             # 0: d_inner // ssm_head_dim
+    ssm_groups: int = 1              # B/C groups; head h reads h // (H/G)
     # --- hybrid (zamba2-style): shared attention block every `period` layers
     shared_attn_period: int = 0
+    # --- hybrid_moe: one character a layer, M = Mamba-2, E = experts,
+    # * = attention; n_layers == len(layer_pattern)
+    layer_pattern: str = ""
     # --- encoder-decoder (whisper) ---
     enc_dec: bool = False
     n_enc_layers: int = 0
     # --- misc ---
     mlp_act: str = "swiglu"          # swiglu | gelu
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-6           # RMSNorm epsilon
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     source: str = ""                 # citation
@@ -69,16 +85,27 @@ class ArchConfig:
             object.__setattr__(self, "moe_d_ff", self.d_ff)
         if self.enc_dec and self.n_enc_layers == 0:
             object.__setattr__(self, "n_enc_layers", self.n_layers)
+        if self.layer_pattern and len(self.layer_pattern) != self.n_layers:
+            raise ValueError(f"{self.name}: layer_pattern has "
+                             f"{len(self.layer_pattern)} layers, n_layers "
+                             f"{self.n_layers}")
 
     # ------------------------------------------------------------------ sizes
     @property
     def d_inner(self) -> int:
-        """Mamba2 inner width."""
+        """Mamba2 inner width: the heads' total where they are given."""
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
-        return self.d_inner // self.ssm_head_dim
+        return self.ssm_n_heads or self.d_inner // self.ssm_head_dim
+
+    @property
+    def router_experts(self) -> int:
+        """Experts the router scores (the held ones and any others)."""
+        return self.n_router_experts or self.n_experts
 
     @property
     def is_attention_free(self) -> bool:
@@ -105,7 +132,8 @@ class ArchConfig:
         total = param_count(self)
         if self.n_experts:
             expert = param_count(self, experts_only=True)
-            total = total - expert + expert * self.top_k // self.n_experts
+            total = total - expert \
+                + expert * self.top_k // self.router_experts
         return total
 
     def model_flops_per_token(self) -> float:
@@ -114,7 +142,15 @@ class ArchConfig:
 
     # --------------------------------------------------------------- variants
     def reduced(self) -> "ArchConfig":
-        """CPU smoke variant: same family/topology, tiny dimensions."""
+        """CPU smoke variant: same family/topology, tiny dimensions. A
+        layer pattern keeps each of its kinds: the kinds in their first
+        order, all but the last twice (``MEME*`` for Mamba-2, expert and
+        attention layers), so that a repeat is scanned."""
+        pattern = ""
+        if self.layer_pattern:
+            kinds = "".join(dict.fromkeys(self.layer_pattern))
+            pattern = kinds[:-1] * 2 + kinds[-1] if len(kinds) > 1 \
+                else kinds * 2
         d_model = min(self.d_model, 256)
         n_heads = min(self.n_heads, 4) if self.n_heads else 0
         n_kv = 0
@@ -125,7 +161,8 @@ class ArchConfig:
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=2,
+            n_layers=len(pattern) or 2,
+            layer_pattern=pattern,
             n_enc_layers=2 if self.enc_dec else 0,
             d_model=d_model,
             n_heads=n_heads,
@@ -136,6 +173,10 @@ class ArchConfig:
             vocab=min(self.vocab, 512),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            n_router_experts=min(self.n_router_experts, 4),
+            shared_expert_d_ff=min(self.shared_expert_d_ff, 256),
+            ssm_n_heads=min(self.ssm_n_heads, 8),
+            ssm_groups=min(self.ssm_groups, 2),
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=min(self.ssm_head_dim, 32) if self.ssm_state else 64,
             ssm_chunk=32,
@@ -191,5 +232,5 @@ def _load_all():
     from repro.configs import (  # noqa: F401
         starcoder2_7b, olmoe_1b_7b, zamba2_2_7b, whisper_large_v3,
         qwen2_vl_72b, qwen1_5_110b, arctic_480b, llama3_405b,
-        mamba2_780m, h2o_danube_3_4b)
+        mamba2_780m, h2o_danube_3_4b, nemotron_3_nano_30b_a3b)
     _LOADED = True

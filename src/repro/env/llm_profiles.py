@@ -80,7 +80,7 @@ def paper_pool(scenario: str = "sciq") -> Pool:
 
 
 def zoo_pool(seed: int = 0) -> Pool:
-    """End-to-end mode: the 10 assigned architectures as the arm pool.
+    """End-to-end mode: the registry's 11 architectures as the arm pool.
 
     Cost ∝ active-parameter FLOPs (6·N_active per token); quality is a
     monotone-but-noisy function of active params (bigger is better on

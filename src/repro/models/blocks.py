@@ -1,7 +1,9 @@
-"""Per-layer blocks for the six architecture families."""
+"""Per-layer blocks for the architecture families; `LAYER_KINDS` holds the
+layer kinds a hybrid_moe `layer_pattern` is made of."""
 from __future__ import annotations
 
-from typing import Optional
+import dataclasses
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -165,3 +167,81 @@ def apply_decoder_block_decode(cfg, p, x, self_cache, cross_cache, pos):
     x = x + h
     x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln3"], x))
     return x, self_cache
+
+
+# ------------------------------------------------------------ hybrid_moe
+# One entry per layer kind of a `layer_pattern`. Each layer is pre-norm
+# with a residual, x + mixer(norm(x)); weights of one kind are stacked
+# under `params`, decode caches under `cache`. The walker in
+# `models.model` applies a kind's prefill and decode inside
+# `jax.named_scope(scope)`, which names the kind's ops on the device trace.
+def _mixer_schema(key, mixer_schema):
+    return lambda cfg: {"ln1": norm_schema(cfg), key: mixer_schema(cfg)}
+
+
+def _held_moe_apply(cfg, p, x, positions=None):
+    return x + moe_mod.apply_held_moe(cfg, p["moe"],
+                                      apply_norm(cfg, p["ln1"], x))
+
+
+def _attn_apply(cfg, p, x, positions):
+    return x + attn_mod.apply_attention(cfg, p["attn"],
+                                        apply_norm(cfg, p["ln1"], x),
+                                        positions)
+
+
+def _attn_prefill(cfg, p, x, positions, cache_len, cache_dtype):
+    h, c = attn_mod.apply_attention_prefill(
+        cfg, p["attn"], apply_norm(cfg, p["ln1"], x), positions, cache_len,
+        cache_dtype=cache_dtype)
+    return x + h, c
+
+
+def _attn_decode(cfg, p, x, cache, pos):
+    h, c = attn_mod.apply_attention_decode(
+        cfg, p["attn"], apply_norm(cfg, p["ln1"], x), cache, pos)
+    return x + h, c
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    params: str                 # key of the kind's stacked weights
+    cache: Optional[str]        # key of its stacked decode cache, or None
+    scope: str                  # named scope of its prefill and decode
+    schema: Callable            # cfg -> one layer's schema
+    apply: Callable             # (cfg, p, x, positions) -> x
+    prefill: Callable           # (cfg, p, x, positions, cache_len, dtype)
+                                #   -> (x, cache)
+    decode: Callable            # (cfg, p, x, cache, pos) -> (x, cache)
+    init_cache: Optional[Callable]  # (cfg, batch, cache_len, dtype)
+                                    #   -> one layer's cache
+    cache_axes: Optional[Callable]  # () -> that cache's logical axes
+
+
+LAYER_KINDS = {
+    "M": LayerKind(
+        params="mamba", cache="ssm", scope="ssm_layer",
+        schema=ssm_block_schema,
+        apply=lambda cfg, p, x, positions: apply_ssm_block(cfg, p, x),
+        prefill=lambda cfg, p, x, positions, cache_len, dt:
+            apply_ssm_block_prefill(cfg, p, x, cache_dtype=dt),
+        decode=lambda cfg, p, x, cache, pos:
+            apply_ssm_block_decode(cfg, p, x, cache),
+        init_cache=lambda cfg, batch, cache_len, dt:
+            ssm_mod.init_ssm_cache(cfg, batch, dt),
+        cache_axes=ssm_mod.ssm_cache_axes),
+    "E": LayerKind(
+        params="moe", cache=None, scope="moe_layer",
+        schema=_mixer_schema("moe", moe_mod.held_moe_schema),
+        apply=_held_moe_apply,
+        prefill=lambda cfg, p, x, positions, cache_len, dt:
+            (_held_moe_apply(cfg, p, x), None),
+        decode=lambda cfg, p, x, cache, pos: (_held_moe_apply(cfg, p, x),
+                                              None),
+        init_cache=None, cache_axes=None),
+    "*": LayerKind(
+        params="attn", cache="attn", scope="attn_layer",
+        schema=_mixer_schema("attn", attn_mod.attention_schema),
+        apply=_attn_apply, prefill=_attn_prefill, decode=_attn_decode,
+        init_cache=attn_mod.init_cache, cache_axes=attn_mod.cache_axes),
+}

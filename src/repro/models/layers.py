@@ -109,7 +109,7 @@ def norm_schema(cfg, d: Optional[int] = None):
 def apply_norm(cfg, p, x):
     if cfg.norm == "layernorm":
         return layernorm(x, p["scale"], p["bias"])
-    return rmsnorm(x, p["scale"])
+    return rmsnorm(x, p["scale"], cfg.norm_eps)
 
 
 # ----------------------------------------------------------------- mlp

@@ -4,6 +4,9 @@ Layers are *stacked* (leading ``layers`` dim) and iterated with
 ``jax.lax.scan`` so 80–126-layer configs compile quickly; hybrid models scan
 over groups of ``shared_attn_period`` Mamba2 layers with the weight-shared
 attention block applied once per group (no lax.cond — honest cost analysis).
+hybrid_moe models walk their ``layer_pattern`` (see `walk_pattern`): one
+stack and one cache per layer kind (`blocks.LAYER_KINDS`), the longest
+repeated stretch scanned over its unit.
 """
 from __future__ import annotations
 
@@ -45,6 +48,11 @@ def model_schema(cfg: ArchConfig):
         assert cfg.n_layers % cfg.shared_attn_period == 0
         s["layers"] = stack_layers(blocks.ssm_block_schema(cfg), cfg.n_layers)
         s["shared"] = blocks.dense_block_schema(cfg)   # weight-shared attn block
+    elif cfg.family == "hybrid_moe":
+        for ch in sorted(set(cfg.layer_pattern)):
+            kind = blocks.LAYER_KINDS[ch]
+            s[kind.params] = stack_layers(kind.schema(cfg),
+                                          cfg.layer_pattern.count(ch))
     elif cfg.family == "audio":
         s["enc_layers"] = stack_layers(blocks.dense_block_schema(cfg),
                                        cfg.n_enc_layers)
@@ -78,8 +86,12 @@ def param_count(cfg: ArchConfig, experts_only: bool = False) -> int:
     if experts_only:
         if not cfg.n_experts:
             return 0
-        moe = schema["layers"]["moe"]
-        sub = {k: moe[k] for k in ("wi_gate", "wi_up", "wo")}
+        if cfg.family == "hybrid_moe":
+            moe = schema[blocks.LAYER_KINDS["E"].params]["moe"]
+            sub = {k: moe[k] for k in ("up", "down")}
+        else:
+            moe = schema["layers"]["moe"]
+            sub = {k: moe[k] for k in ("wi_gate", "wi_up", "wo")}
         return count_from_schema(sub)
     return count_from_schema(schema)
 
@@ -127,6 +139,89 @@ def _scan_blocks(body, x, stacked, n: int, remat: bool):
 def _group_stacked(tree, groups: int):
     return jax.tree.map(
         lambda a: a.reshape((groups, a.shape[0] // groups) + a.shape[1:]), tree)
+
+
+# =============================================================== pattern
+def pattern_plan(pattern: str):
+    """[(unit, reps)] covering ``pattern`` in order: the stretch that
+    repeats one unit and saves the most layers, (unit, reps >= 2), with what
+    lies before and after it as units of one repeat."""
+    n = len(pattern)
+    best = (0, 0, n, 1)                       # saved, offset, unit, reps
+    for off in range(n):
+        for size in range(1, (n - off) // 2 + 1):
+            unit, reps = pattern[off:off + size], 1
+            while pattern[off + reps * size:off + (reps + 1) * size] == unit:
+                reps += 1
+            if (reps - 1) * size > best[0]:
+                best = ((reps - 1) * size, off, size, reps)
+    _, off, size, reps = best
+    plan = [(pattern[:off], 1)] if off else []
+    plan.append((pattern[off:off + size], reps))
+    if off + size * reps < n:
+        plan.append((pattern[off + size * reps:], 1))
+    return plan
+
+
+# the scope wrapper of a layer kind's ops (a seam for tests)
+_scope = jax.named_scope
+
+
+def walk_pattern(cfg: ArchConfig, x, params, caches, phase: str, *args):
+    """Apply a hybrid_moe model's layers in ``layer_pattern`` order.
+
+    ``phase`` is a `blocks.LayerKind` field: "apply" (no caches), "prefill"
+    or "decode"; ``args`` follow x in its signature. Layer i of kind k uses
+    row j of k's stacked weights and caches (j = kind-k layers before i);
+    caches ({kind cache key: stacked}) are read and written in place at j.
+    The longest repeated stretch (`pattern_plan`) is one `lax.scan` over its
+    unit, so a pattern of any length traces each kind a few times.
+    Returns (x, caches)."""
+    done = {ch: 0 for ch in blocks.LAYER_KINDS}
+
+    def layer(ch, j, x, caches):
+        kind = blocks.LAYER_KINDS[ch]
+        take = (lambda a: a[j]) if isinstance(j, int) else \
+            (lambda a: jax.lax.dynamic_index_in_dim(a, j, keepdims=False))
+        p = jax.tree.map(take, params[kind.params])
+        fn = getattr(kind, phase)
+        with _scope(kind.scope):
+            if phase == "apply":
+                return fn(cfg, p, x, *args), caches
+            if phase == "decode":
+                c = jax.tree.map(take, caches[kind.cache]) if kind.cache \
+                    else None
+                x, c = fn(cfg, p, x, c, *args)
+            else:
+                x, c = fn(cfg, p, x, *args)
+        if kind.cache:
+            caches = dict(caches)
+            caches[kind.cache] = jax.tree.map(
+                lambda big, new: jax.lax.dynamic_update_index_in_dim(
+                    big, new.astype(big.dtype), j, 0),
+                caches[kind.cache], c)
+        return x, caches
+
+    for unit, reps in pattern_plan(cfg.layer_pattern):
+        first = dict(done)
+        per = {ch: unit.count(ch) for ch in blocks.LAYER_KINDS}
+
+        def body(carry, rep, unit=unit, first=first, per=per):
+            x, caches = carry
+            seen = {ch: 0 for ch in blocks.LAYER_KINDS}
+            for ch in unit:
+                j = first[ch] + rep * per[ch] + seen[ch]
+                seen[ch] += 1
+                x, caches = layer(ch, j, x, caches)
+            return (x, caches), None
+
+        if reps == 1:
+            (x, caches), _ = body((x, caches), 0)
+        else:
+            (x, caches), _ = _scan(body, (x, caches), jnp.arange(reps))
+        for ch in blocks.LAYER_KINDS:
+            done[ch] += per[ch] * reps
+    return x, caches
 
 
 # ===================================================================== forward
@@ -178,6 +273,8 @@ def forward(cfg: ArchConfig, params, inputs: Dict[str, Any], *,
             return _scan_blocks(inner, h, p_g, period, False)
 
         x = _scan_blocks(group_body, x, grouped, groups, remat)
+    elif cfg.family == "hybrid_moe":
+        x, _ = walk_pattern(cfg, x, params, {}, "apply", positions)
 
     x = apply_norm(cfg, params["lnf"], x)
     logits = unembed(cfg, params["embed"], x)
@@ -318,6 +415,10 @@ def prefill(cfg: ArchConfig, params, inputs: Dict[str, Any], max_len: int, *,
         cache = {"ssm": jax.tree.map(
                     lambda a: a.reshape((cfg.n_layers,) + a.shape[2:]), ssm_c),
                  "shared_attn": attn_c}
+    elif cfg.family == "hybrid_moe":
+        cache, _ = init_decode_caches(cfg, b, max_len, cdt)
+        x, cache = walk_pattern(cfg, x, params, cache, "prefill", positions,
+                                attn_len, cdt)
     else:
         raise ValueError(cfg.family)
 
@@ -404,6 +505,15 @@ def init_decode_caches(cfg: ArchConfig, batch: int, max_len: int,
                  "shared_attn": stackz(attn_one, groups)}
         axes = {"ssm": _with_layers(ssm_mod.ssm_cache_axes()),
                 "shared_attn": _with_layers(attn_mod.cache_axes())}
+    elif cfg.family == "hybrid_moe":
+        cache, axes = {}, {}
+        for ch in sorted(set(cfg.layer_pattern)):
+            kind = blocks.LAYER_KINDS[ch]
+            if kind.cache:
+                one = shape_only(lambda kind=kind: kind.init_cache(
+                    cfg, batch, attn_len, dtype))
+                cache[kind.cache] = stackz(one, cfg.layer_pattern.count(ch))
+                axes[kind.cache] = _with_layers(kind.cache_axes())
     elif cfg.family == "audio":
         self_one = shape_only(lambda: attn_mod.init_cache(
             cfg, batch, attn_len, dtype))
@@ -499,6 +609,8 @@ def decode_step(cfg: ArchConfig, params, tokens, cache, pos):
         x, new = _scan(body, x,
                        (params["layers"], cache["self"], cache["cross"]))
         cache = {"self": new, "cross": cache["cross"]}
+    elif cfg.family == "hybrid_moe":
+        x, cache = walk_pattern(cfg, x, params, cache, "decode", pos)
 
     x = apply_norm(cfg, params["lnf"], x)
     return unembed(cfg, params["embed"], x), cache

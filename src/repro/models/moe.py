@@ -250,3 +250,76 @@ def apply_moe_auto(cfg, p, x):
         batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
         return apply_moe_ep(cfg, p, x, mesh=mesh, batch_axes=batch_axes)
     return apply_moe(cfg, p, x)
+
+
+# ============================================================ held share
+# The expert layer of one expert-parallel rank, dropless: the router scores
+# all `router_experts` experts and picks `top_k` of them for every token;
+# this layer holds `n_experts` of those, [expert_offset, expert_offset +
+# n_experts), and adds their part of the result (the other ranks' part is
+# theirs to add). Every held expert runs over every token with the
+# token's combine weight, zero where it was not routed there: no capacity,
+# so no assignment is ever dropped, and in decode each held expert's
+# weights are read once a step, which the step needs anyway. Prefill runs
+# in token blocks of HELD_TOKEN_BLOCK so that the (tokens, experts, d_ff)
+# activations stay bounded. Experts, and the shared expert that runs on
+# every token, are relu2: down(relu(up x)^2) (Nemotron-H).
+HELD_TOKEN_BLOCK = 2048
+
+
+def held_moe_schema(cfg):
+    d, e, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    fs, r = cfg.shared_expert_d_ff, cfg.router_experts
+    std_o = 0.02 / math.sqrt(2 * max(cfg.n_layers, 1))
+    return {"router": ParamSpec((d, r), (None, None)),
+            "router_bias": ParamSpec((r,), (None,)),
+            "up": ParamSpec((e, d, f), ("experts", None, "embed_fsdp")),
+            "down": ParamSpec((e, f, d), ("experts", "embed_fsdp", None),
+                              std=std_o),
+            "shared_up": ParamSpec((d, fs), ("embed_fsdp", "mlp")),
+            "shared_down": ParamSpec((fs, d), ("mlp", "embed_fsdp"),
+                                     std=std_o)}
+
+
+def route_held(cfg, p, x):
+    """Combine weights (T, n_experts) f32 of the held experts. x (T, D).
+
+    DeepSeek-V3-style sigmoid router: scores s = sigmoid(x W) in float32
+    over all `router_experts`; the top_k by s + correction bias (the bias
+    only chooses); their s normalised to sum 1 and scaled by
+    `routed_scale`; the held experts' columns kept."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["router_bias"].astype(jnp.float32),
+                           cfg.top_k)                         # (T, K)
+    w = jnp.take_along_axis(scores, idx, -1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    local = idx - cfg.expert_offset                           # held: [0, E)
+    onehot = jax.nn.one_hot(local, cfg.n_experts, dtype=jnp.float32)
+    return jnp.einsum("tk,tke->te", w, onehot)                # off-rank: 0
+
+
+def _held_block(cfg, p, x):
+    """x (T, D) -> (T, D)."""
+    cw = route_held(cfg, p, x)
+    h = jnp.square(jax.nn.relu(jnp.einsum("td,edf->tef", x, p["up"])))
+    y = jnp.einsum("tef,efd->td", h * cw[..., None].astype(h.dtype),
+                   p["down"])
+    return y + jnp.square(jax.nn.relu(x @ p["shared_up"])) @ p["shared_down"]
+
+
+def apply_held_moe(cfg, p, x):
+    """The held experts' part of the layer, plus the shared expert, for
+    every token of x (B, S, D), dropless. Returns y (B, S, D)."""
+    b, s, d = x.shape
+    t = b * s
+    xt = x.reshape(t, d)
+    if t <= HELD_TOKEN_BLOCK:
+        return _held_block(cfg, p, xt).reshape(b, s, d)
+    nb = -(-t // HELD_TOKEN_BLOCK)
+    xt = jnp.pad(xt, ((0, nb * HELD_TOKEN_BLOCK - t), (0, 0)))
+    y = jax.lax.map(lambda xb: _held_block(cfg, p, xb),
+                    xt.reshape(nb, HELD_TOKEN_BLOCK, d))
+    return y.reshape(nb * HELD_TOKEN_BLOCK, d)[:t].reshape(b, s, d)

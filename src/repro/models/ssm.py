@@ -2,7 +2,10 @@
 
 Layer structure follows the Mamba2 paper: in_proj -> [z | xBC | dt],
 depthwise causal conv over xBC, SSD core over heads, gated RMSNorm,
-out_proj. The SSD core uses the chunkwise dual form: intra-chunk quadratic
+out_proj. B and C come in ``ssm_groups`` groups (head h reads group
+h // (H/G)) and the gated norm is taken over each group's share of the
+inner width; with one group (mamba2) both are the full-width forms. The SSD
+core uses the chunkwise dual form: intra-chunk quadratic
 ("attention-like", MXU-friendly) term + inter-chunk state recurrence via an
 associative scan. ``repro.kernels.ssd_scan`` is the Pallas TPU kernel for the
 intra-chunk term; this module is the pure-XLA implementation used on CPU and
@@ -24,7 +27,7 @@ CONV_WIDTH = 4
 
 def ssm_schema(cfg):
     d, di, h, n = cfg.d_model, cfg.d_inner, cfg.ssm_heads, cfg.ssm_state
-    g = 1  # B/C groups
+    g = cfg.ssm_groups
     conv_ch = di + 2 * g * n
     proj_out = 2 * di + 2 * g * n + h
     std_o = 0.02 / math.sqrt(2 * max(cfg.n_layers, 1))
@@ -41,11 +44,34 @@ def ssm_schema(cfg):
 
 
 def _split_proj(cfg, zxbcdt):
-    di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    di, gn = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
     z = zxbcdt[..., :di]
-    xbc = zxbcdt[..., di:2 * di + 2 * n]
-    dt = zxbcdt[..., 2 * di + 2 * n:]
+    xbc = zxbcdt[..., di:2 * di + 2 * gn]
+    dt = zxbcdt[..., 2 * di + 2 * gn:]
     return z, xbc, dt
+
+
+def _split_xbc(cfg, xbc):
+    """x, B, C of the conv's output; B and C (..., G, N) where G > 1."""
+    di, n, g = cfg.d_inner, cfg.ssm_state, cfg.ssm_groups
+    xin, bmat, cmat = (xbc[..., :di], xbc[..., di:di + g * n],
+                       xbc[..., di + g * n:])
+    if g > 1:
+        bmat = bmat.reshape(bmat.shape[:-1] + (g, n))
+        cmat = cmat.reshape(cmat.shape[:-1] + (g, n))
+    return xin, bmat, cmat
+
+
+def gated_norm(cfg, y, z, scale):
+    """RMSNorm of y * silu(z), over each of the ``ssm_groups`` groups of
+    the inner width (the whole width for one group)."""
+    y = y * jax.nn.silu(z)
+    g = cfg.ssm_groups
+    if g == 1:
+        return rmsnorm(y, scale, cfg.norm_eps)
+    shape = y.shape
+    y = y.reshape(shape[:-1] + (g, shape[-1] // g))
+    return rmsnorm(y, scale.reshape(g, -1), cfg.norm_eps).reshape(shape)
 
 
 def causal_conv(xbc, w, b):
@@ -129,6 +155,33 @@ def ssd_chunked(xh, dt, a, bmat, cmat, chunk: int, init_state=None,
     return y
 
 
+def ssd(xh, dt, a, bmat, cmat, chunk: int, init_state=None,
+        return_state: bool = False):
+    """`ssd_chunked` for B/C of one group (B,S,N) or of G groups
+    (B,S,G,N): each group's heads run as one group, vmapped over groups."""
+    if bmat.ndim == 3:
+        return ssd_chunked(xh, dt, a, bmat, cmat, chunk,
+                           init_state=init_state, return_state=return_state)
+    b, s, h, p = xh.shape
+    g, n = bmat.shape[2], bmat.shape[3]
+    hg = h // g
+
+    def one(xg, dg, ag, bg, cg, ig):
+        return ssd_chunked(xg, dg, ag, bg, cg, chunk, init_state=ig,
+                           return_state=return_state)
+
+    init = None if init_state is None else \
+        init_state.reshape(b, g, hg, p, n)
+    out = jax.vmap(one, in_axes=(2, 2, 0, 2, 2, None if init is None else 1),
+                   out_axes=(2, 1) if return_state else 2)(
+        xh.reshape(b, s, g, hg, p), dt.reshape(b, s, g, hg),
+        a.reshape(g, hg), bmat, cmat, init)
+    if return_state:
+        y, final = out
+        return y.reshape(b, s, h, p), final.reshape(b, h, p, n)
+    return out.reshape(b, s, h, p)
+
+
 def apply_ssm(cfg, p, x, *, init_state=None, return_state: bool = False):
     """Full-sequence Mamba2 layer. x (B,S,D)."""
     b, s, _ = x.shape
@@ -136,17 +189,17 @@ def apply_ssm(cfg, p, x, *, init_state=None, return_state: bool = False):
     zxbcdt = jnp.einsum("bsd,dk->bsk", x, p["w_in"])
     z, xbc, dt = _split_proj(cfg, zxbcdt)
     xbc = causal_conv(xbc, p["conv_w"], p["conv_b"])
-    xin, bmat, cmat = xbc[..., :di], xbc[..., di:di + n], xbc[..., di + n:]
+    xin, bmat, cmat = _split_xbc(cfg, xbc)
     xh = xin.reshape(b, s, h, hp)
     xh = shard(xh, "batch", "seq", "ssm_heads", None)
     dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    out = ssd_chunked(xh, dtv, a, bmat, cmat, cfg.ssm_chunk,
-                      init_state=init_state, return_state=return_state)
+    out = ssd(xh, dtv, a, bmat, cmat, cfg.ssm_chunk,
+              init_state=init_state, return_state=return_state)
     y, final = out if return_state else (out, None)
     y = y + xh.astype(jnp.float32) * p["d_skip"][None, None, :, None]
     y = y.reshape(b, s, di).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"])
+    y = gated_norm(cfg, y, z, p["norm"])
     y = jnp.einsum("bsk,kd->bsd", y, p["w_out"])
     if return_state:
         return y, final
@@ -167,16 +220,15 @@ def apply_ssm_prefill(cfg, p, x, *, cache_dtype=None):
     zxbcdt = jnp.einsum("bsd,dk->bsk", x, p["w_in"])
     z, xbc_raw, dt = _split_proj(cfg, zxbcdt)
     xbc = causal_conv(xbc_raw, p["conv_w"], p["conv_b"])
-    xin, bmat, cmat = xbc[..., :di], xbc[..., di:di + n], xbc[..., di + n:]
+    xin, bmat, cmat = _split_xbc(cfg, xbc)
     xh = xin.reshape(b, s, h, hp)
     xh = shard(xh, "batch", "seq", "ssm_heads", None)
     dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    y, final = ssd_chunked(xh, dtv, a, bmat, cmat, cfg.ssm_chunk,
-                           return_state=True)
+    y, final = ssd(xh, dtv, a, bmat, cmat, cfg.ssm_chunk, return_state=True)
     y = y + xh.astype(jnp.float32) * p["d_skip"][None, None, :, None]
     y = y.reshape(b, s, di).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"])
+    y = gated_norm(cfg, y, z, p["norm"])
     y = jnp.einsum("bsk,kd->bsd", y, p["w_out"])
 
     w = CONV_WIDTH - 1
@@ -192,7 +244,7 @@ def apply_ssm_prefill(cfg, p, x, *, cache_dtype=None):
 # --------------------------------------------------------------- decode
 def init_ssm_cache(cfg, batch: int, dtype):
     di, n, h, hp = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-    conv_ch = di + 2 * n
+    conv_ch = di + 2 * cfg.ssm_groups * n
     return {
         "conv": jnp.zeros((batch, CONV_WIDTH - 1, conv_ch), dtype),
         "state": jnp.zeros((batch, h, hp, n), jnp.float32),
@@ -214,18 +266,26 @@ def apply_ssm_decode(cfg, p, x, cache):
     conv_out = jnp.einsum("bwc,wc->bc", window, p["conv_w"]) + p["conv_b"]
     conv_out = jax.nn.silu(conv_out)
     new_conv = window[:, 1:]
-    xin, bmat, cmat = (conv_out[..., :di], conv_out[..., di:di + n],
-                       conv_out[..., di + n:])
+    xin, bmat, cmat = _split_xbc(cfg, conv_out)
     xh = xin.reshape(b, h, hp)
     dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # (B,H)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
     g = jnp.exp(dtv * a)                                       # (B,H)
     xd = xh.astype(jnp.float32) * dtv[..., None]
-    upd = jnp.einsum("bhp,bn->bhpn", xd, bmat.astype(jnp.float32))
-    state = cache["state"] * g[..., None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", state, cmat.astype(jnp.float32))
+    if bmat.ndim == 2:
+        upd = jnp.einsum("bhp,bn->bhpn", xd, bmat.astype(jnp.float32))
+        state = cache["state"] * g[..., None, None] + upd
+        y = jnp.einsum("bhpn,bn->bhp", state, cmat.astype(jnp.float32))
+    else:                                      # B/C (B,G,N): heads by group
+        ng = bmat.shape[1]
+        upd = jnp.einsum("bghp,bgn->bghpn", xd.reshape(b, ng, h // ng, hp),
+                         bmat.astype(jnp.float32)).reshape(b, h, hp, n)
+        state = cache["state"] * g[..., None, None] + upd
+        y = jnp.einsum("bghpn,bgn->bghp",
+                       state.reshape(b, ng, h // ng, hp, n),
+                       cmat.astype(jnp.float32)).reshape(b, h, hp)
     y = y + xh.astype(jnp.float32) * p["d_skip"][None, :, None]
     y = y.reshape(b, di).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"])
+    y = gated_norm(cfg, y, z, p["norm"])
     y = jnp.einsum("bk,kd->bd", y, p["w_out"])[:, None, :]
     return y, {"conv": new_conv, "state": state}

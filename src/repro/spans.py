@@ -37,6 +37,13 @@ The spans, outermost first:
 
 The scheduler's cumulative counters, the same quantities without a
 profiler, are in `serving.scheduler.ContinuousScheduler.stats`.
+
+Device-side, a hybrid_moe model's layers run inside named scopes
+(`jax.named_scope`, in `models.model.walk_pattern`): `ssm_layer`,
+`moe_layer` and `attn_layer` around each Mamba-2, expert and attention
+layer. They are not host spans: they name the ops, whose scope path the
+device trace carries in each op's metadata (e.g.
+`jit(_decode_chunk)/.../moe_layer/...`), and they change no output.
 """
 from jax.profiler import TraceAnnotation as span
 

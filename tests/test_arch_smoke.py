@@ -32,7 +32,12 @@ def get_params(cfg, params_cache):
 @pytest.mark.parametrize("arch", ARCHS)
 def test_reduced_config_invariants(arch):
     cfg = get_config(arch).reduced()
-    assert cfg.n_layers == 2
+    full = get_config(arch)
+    if full.layer_pattern:          # one of each kind, a repeat to scan
+        assert set(cfg.layer_pattern) == set(full.layer_pattern)
+        assert cfg.n_layers == len(cfg.layer_pattern) <= 5
+    else:
+        assert cfg.n_layers == 2
     assert cfg.d_model <= 512
     if cfg.n_experts:
         assert cfg.n_experts <= 4

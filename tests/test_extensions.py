@@ -28,7 +28,7 @@ def test_async_batching_reuses_actions():
 
 def test_zoo_pool_prices_follow_active_params():
     pool = zoo_pool()
-    assert pool.k == 10
+    assert pool.k == 11
     names = list(pool.names)
     # llama3-405b must be the most expensive arm; mamba2-780m near cheapest
     assert pool.mean_cost[names.index("llama3-405b")] == pool.mean_cost.max()

@@ -40,7 +40,7 @@ def test_parse_collective_bytes_kinds():
 
 
 def test_input_specs_all_pairs_abstract():
-    """All 40 pairs produce allocation-free specs with coherent shapes."""
+    """All 44 pairs produce allocation-free specs with coherent shapes."""
     count = 0
     for arch in list_archs():
         cfg = get_config(arch)
@@ -54,7 +54,7 @@ def test_input_specs_all_pairs_abstract():
             for k, v in inputs.items():
                 assert isinstance(v, jax.ShapeDtypeStruct), (arch, name, k)
                 assert v.shape[0] == shape.global_batch
-    assert count == 40
+    assert count == 44
 
 
 def test_runnable_long_500k_policy():
