@@ -299,15 +299,17 @@ def apply_attention_decode(cfg, p, x, cache, pos, *, window="cfg",
     k, v = cache["k"], cache["v"]
     b, t, kvh, hd = k.shape
     h = q.shape[2]
-    k = _repeat_kv(k, h // kvh)
-    v = _repeat_kv(v, h // kvh)
+    # Grouped-query form: query head g*rep + r reads KV head g straight from
+    # the cache (the head order `_repeat_kv` gives), so each step streams
+    # the cache once instead of an H/KV-times repeated copy of it.
+    qg = q.reshape(b, kvh, h // kvh, hd)
     # Flash-decode sharding (EXPERIMENTS.md §Perf C1): pin the cache_seq
-    # sharding through repeat/scores/softmax so SPMD computes per-shard
-    # partial attention and combines with tiny all-reduces instead of
-    # all-gathering the whole KV cache every step.
+    # sharding through scores/softmax so SPMD computes per-shard partial
+    # attention and combines with tiny all-reduces instead of all-gathering
+    # the whole KV cache every step.
     k = shard(k, "batch", "cache_seq", None, None)
     v = shard(v, "batch", "cache_seq", None, None)
-    sc = jnp.einsum("bshk,bthk->bhst", q, k).astype(jnp.float32)
+    sc = jnp.einsum("bgrk,btgk->bgrt", qg, k).astype(jnp.float32)
     sc = sc / math.sqrt(hd)
     sc = shard(sc, "batch", None, None, "cache_seq")
     if not cross:
@@ -321,5 +323,5 @@ def apply_attention_decode(cfg, p, x, cache, pos, *, window="cfg",
             valid = kidx <= pos_r[:, None]
         sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
     w = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
-    o = jnp.einsum("bhst,bthk->bshk", w, v)
+    o = jnp.einsum("bgrt,btgk->bgrk", w, v).reshape(b, 1, h, hd)
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), cache

@@ -11,11 +11,13 @@ serve benchmark call its speedup a scheduling win).
 
 Also covered here: the prefill half of the split vs the full forward, EOS
 forcing/freezing semantics, per-row decode-attention positions (partial
-slot fills) vs the jnp oracle, the jitted M=1 `cloud.select` pad path vs
-the numpy reference, and sequential≡continuous at the service level for
-both SUC and the AWC cascade.
+slot fills) vs the jnp oracle, the model's grouped-query decode
+attention vs K/V repeated to every query head, the jitted M=1
+`cloud.select` pad path vs the numpy reference, and sequential≡continuous
+at the service level for both SUC and the AWC cascade.
 """
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,7 @@ from repro.core import rounding
 from repro.core.policies import PolicyConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.kernels import ops, ref
+from repro.models import attention as attn
 from repro.models import model as M
 from repro.router.cloud import Replica, SchedulingCloud, _pad_to_n_np
 from repro.router.service import MultiLLMService
@@ -239,6 +242,88 @@ def test_decode_attention_scalar_pos_unchanged():
     out = ops.decode_attention(q, k, v, jnp.int32(17), interpret=True)
     want = ref.decode_attention(q, k, v, jnp.full((b,), 17, jnp.int32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+# ================================= grouped decode attention (model path)
+def _repeat_kv_decode(p, x, cache, pos, *, window, cross):
+    """`attn.apply_attention_decode` for a rope-free config, written out with
+    K/V repeated to every query head: the oracle for the grouped form."""
+    b = x.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    k, v = cache["k"], cache["v"]
+    t, kvh, hd = k.shape[1:]
+    if not cross:
+        slot = pos % t if window is not None else pos
+        rows = jnp.arange(b)
+        k = k.at[rows, slot].set(
+            jnp.einsum("bd,dhk->bhk", x[:, 0], p["wk"]).astype(k.dtype))
+        v = v.at[rows, slot].set(
+            jnp.einsum("bd,dhk->bhk", x[:, 0], p["wv"]).astype(v.dtype))
+    rep = q.shape[2] // kvh
+    kr, vr = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    sc = jnp.einsum("bshk,bthk->bhst", q, kr).astype(jnp.float32)
+    sc = sc / np.sqrt(hd)
+    if not cross:
+        slots = jnp.arange(t)[None, :]
+        if window is not None:
+            age = (pos[:, None] % t - slots) % t
+            valid = age < jnp.minimum(pos[:, None] + 1, t)
+        else:
+            valid = slots <= pos[:, None]
+        sc = jnp.where(valid[:, None, None, :], sc, attn.NEG_INF)
+    w = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
+    o = jnp.einsum("bhst,bthk->bshk", w, vr)
+    return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), {"k": k, "v": v}
+
+
+# (window, pos): per-row positions hit the first slot, the middle and the
+# last; the ring's per-row positions wrap past the cache length
+_DECODE_MASKS = {
+    "plain-rows": (None, [0, 7, 15]),
+    "plain-scalar": (None, 9),
+    "ring-rows": (16, [3, 16, 37]),
+    "ring-scalar": (16, 21),
+    "cross": ("cross", [0, 7, 15]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mask", sorted(_DECODE_MASKS))
+@pytest.mark.parametrize("rep", [1, 4, 16])
+def test_grouped_decode_attention_matches_repeated_kv(rep, mask, dtype):
+    """The grouped-query decode reads each KV head in place; it must give
+    what repeating K/V to every query head gives, for MHA and GQA ratios,
+    plain, ring and cross caches, per-row and scalar positions."""
+    window, pos = _DECODE_MASKS[mask]
+    cross = window == "cross"
+    window = None if cross else window
+    dt = jnp.dtype(dtype)
+    b, t, kvh, hd, d = 3, 16, 2, 8, 32
+    h = kvh * rep
+    key = jax.random.PRNGKey(rep)
+    ks = jax.random.split(key, 7)
+    p = {"wq": jax.random.normal(ks[0], (d, h, hd), dt) * 0.2,
+         "wk": jax.random.normal(ks[1], (d, kvh, hd), dt) * 0.2,
+         "wv": jax.random.normal(ks[2], (d, kvh, hd), dt) * 0.2,
+         "wo": jax.random.normal(ks[3], (h, hd, d), dt) * 0.2}
+    x = jax.random.normal(ks[4], (b, 1, d), dt)
+    cache = {"k": jax.random.normal(ks[5], (b, t, kvh, hd), dt),
+             "v": jax.random.normal(ks[6], (b, t, kvh, hd), dt)}
+    cfg = types.SimpleNamespace(rope="none")
+    pos = jnp.asarray(pos, jnp.int32)
+    out, new = attn.apply_attention_decode(cfg, p, x, cache, pos,
+                                           window=window, cross=cross)
+    want, want_cache = _repeat_kv_decode(p, x, cache, pos, window=window,
+                                         cross=cross)
+    assert out.dtype == dt and out.shape == (b, 1, d)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(new[name]),
+                                      np.asarray(want_cache[name]))
+    tol = 1e-5 if dt == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
 
 
 # ====================================================== select jit path
